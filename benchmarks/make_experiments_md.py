@@ -407,8 +407,8 @@ and accumulates the online-softmax (m, l, acc) in VMEM scratch across
 the block axis.  GQA and absorbed-MLA variants; `partial=True` emits
 the raw flash stats for `collectives.flash_merge`, which is how the
 paged-sharded engine consumes it (one merge collective per layer).
-Dispatch: default ON for TPU backends, jnp gather fallback elsewhere
-(`REPRO_PAGED_KERNEL=1/0` forces either).  Differential coverage:
+Dispatch: ON for TPU backends, jnp gather fallback elsewhere (chosen
+by platform alone; tests patch `enabled()`).  Differential coverage:
 `tests/test_paged_kernel.py` — kernel == dense oracle
 (`kernels/ref.py`) over null/foreign/partially-written pages, sliding
 windows and fully-masked slots; partial stats merged shard-style equal

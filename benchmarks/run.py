@@ -1162,6 +1162,8 @@ def main() -> None:
                          "injection phase")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.scenario == "serve-quality":
         scenario_serve_quality(
             n_requests=args.requests,

@@ -18,7 +18,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _ring_ag_matmul(x, w, axis_name: str, axis_size: int):
@@ -77,7 +77,7 @@ def ag_matmul_overlapped(x: jax.Array, w: jax.Array, mesh: Mesh,
         mesh=mesh,
         in_specs=(P(None, None), P(axis, None)),
         out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, w)
 
@@ -131,5 +131,5 @@ def psum_scatter_matmul(x: jax.Array, w: jax.Array, mesh: Mesh,
                                     tiled=True)
     fn = shard_map(body, mesh=mesh,
                    in_specs=(P(None, axis), P(axis, None)),
-                   out_specs=P(None, axis), check_rep=False)
+                   out_specs=P(None, axis), check_vma=False)
     return fn(x, w)

@@ -1,14 +1,2 @@
 """Pallas TPU kernels for the MoR hot paths (+ ops.py wrappers, ref.py
-oracles).  Validated in interpret mode on CPU; lowering targets TPU."""
-from __future__ import annotations
-
-from jax.experimental.pallas import tpu as _pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-compat shim: jax <= 0.4.x names the Mosaic compiler-param
-    dataclass ``pltpu.TPUCompilerParams``; newer jax renames it to
-    ``pltpu.CompilerParams``.  Prefer the new name when present."""
-    cls = getattr(_pltpu, "CompilerParams", None) \
-        or getattr(_pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+oracles).  Compiled by Mosaic on TPU; interpret mode everywhere else."""

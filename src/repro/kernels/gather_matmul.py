@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _kernel(meta_ref, x_ref, w_ref, o_ref, acc_ref):
     s, k = pl.program_id(0), pl.program_id(1)
@@ -115,7 +113,7 @@ def gather_matmul(x: jax.Array, w: jax.Array, tile_mask: jax.Array, *,
             scratch_shapes=[pltpu.VMEM((tile_m, tile_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(meta, x, w)

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _kernel(mask_ref, x_ref, w_ref, o_ref, acc_ref):
     j, k = pl.program_id(1), pl.program_id(2)
@@ -74,7 +72,7 @@ def masked_matmul(x: jax.Array, w: jax.Array, tile_mask: jax.Array, *,
             scratch_shapes=[pltpu.VMEM((tile_m, tile_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(mask_flat, x, w)
@@ -132,7 +130,7 @@ def masked_matmul_kdim(x: jax.Array, w: jax.Array, tile_mask: jax.Array, *,
             scratch_shapes=[pltpu.VMEM((tile_m, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(mask_flat, x, w)

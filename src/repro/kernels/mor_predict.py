@@ -32,9 +32,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 N_COEF_ROWS = 6
+# each tile verdict is written as one aligned (1, _LANES) row block
+_LANES = 128
 
 
 def _kernel(has_res, x_ref, w_ref, coef_ref, pn_ref, *rest):
@@ -48,27 +49,35 @@ def _kernel(has_res, x_ref, w_ref, coef_ref, pn_ref, *rest):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    xs = jnp.where(x_ref[...] > 0, 1, -1).astype(jnp.int8)   # act: 0 -> -1
-    ws = jnp.where(w_ref[...] >= 0, 1, -1).astype(jnp.int8)  # weight sign
+    # ±1 signs as exact bf16 MXU operands (fp32 accumulation keeps the
+    # dot exact), compared in fp32 so Mosaic never lays out a packed
+    # bf16 mask
+    xs = jnp.where(x_ref[...].astype(jnp.float32) > 0, 1.0, -1.0
+                   ).astype(jnp.bfloat16)                # act: 0 -> -1
+    ws = jnp.where(w_ref[...].astype(jnp.float32) >= 0, 1.0, -1.0
+                   ).astype(jnp.bfloat16)                # weight sign bit
     acc_ref[...] += jax.lax.dot_general(
         xs, ws, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+        preferred_element_type=jnp.float32)
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _done():
-        p_bin = acc_ref[...].astype(jnp.float32)
-        m, b = coef_ref[0, :], coef_ref[1, :]
-        sc, bi = coef_ref[2, :], coef_ref[3, :]
-        en = coef_ref[4, :]
-        p_hat = (m[None, :] * p_bin + b[None, :]) * sc[None, :] + bi[None, :]
+        p_bin = acc_ref[...]
+        m, b = coef_ref[0:1, :], coef_ref[1:2, :]
+        sc, bi = coef_ref[2:3, :], coef_ref[3:4, :]
+        en = coef_ref[4:5, :]
+        p_hat = (m * p_bin + b) * sc + bi
         if has_res:
-            p_hat = p_hat + coef_ref[5, :][None, :] * res_ref[...]
-        pn = pn_ref[...]
+            p_hat = p_hat + coef_ref[5:6, :] * res_ref[...]
+        pn = pn_ref[...].astype(jnp.int32)
         # pn: 0 = proxy predicted non-zero, 1 = proxy predicted zero,
         # 2 = padded row/col (forced skip, so padding never marks a tile
         # live — matches the oracle's pad-with-False reduction)
-        skip = ((p_hat < 0.0) & (en[None, :] > 0.5) & (pn == 1)) | (pn > 1)
-        o_ref[0, 0] = jnp.any(~skip).astype(jnp.int32)
+        skip = ((p_hat < 0.0) & (en > 0.5) & (pn == 1)) | (pn > 1)
+        live = jnp.where(skip, 0, 1).max(axis=1, keepdims=True).max(
+            axis=0, keepdims=True)                       # (1, 1)
+        # the tile's verdict fills its aligned (1, 128) output block
+        o_ref[0] = jnp.broadcast_to(live, o_ref.shape[1:])
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "tile_n", "bk",
@@ -101,14 +110,16 @@ def mor_tile_mask(x: jax.Array, w: jax.Array, coef: jax.Array,
         in_specs.append(pl.BlockSpec((tile_m, tile_n),
                                      lambda i, j, k: (i, j)))
         args.append(residual.astype(jnp.float32))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, has_res),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], grid[1]), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((tile_m, tile_n), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        out_specs=pl.BlockSpec((1, 1, _LANES), lambda i, j, k: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((grid[0], 1, grid[1] * _LANES),
+                                       jnp.int32),
+        scratch_shapes=[pltpu.VMEM((tile_m, tile_n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
+    return out[:, 0, ::_LANES]

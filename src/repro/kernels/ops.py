@@ -1,14 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels: shape padding,
 interpret-mode fallback on CPU, and the MoRLayer-facing helpers.
 
-On this (CPU) container every kernel runs with ``interpret=True`` — the
-kernel body executes in Python against the same BlockSpec tiling the TPU
-would use, so correctness (incl. the scalar-prefetch index plumbing) is
-what is validated here; the lowering targets TPU.
+Off TPU every kernel runs with ``interpret=True`` — the kernel body
+executes in Python against the same BlockSpec tiling the TPU uses, so
+the CPU tests validate correctness (incl. the scalar-prefetch index
+plumbing); on TPU the platform alone selects the Mosaic lowering.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -21,9 +20,7 @@ from repro.kernels import mor_predict as _mp
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false")
+    """Interpret mode exactly when the platform is not a TPU."""
     return jax.default_backend() != "tpu"
 
 

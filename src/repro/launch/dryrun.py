@@ -1,4 +1,6 @@
 import os
+# a described-device tool: 512 placeholder CPU devices, never the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("REPRO_EXTRA_XLA_FLAGS", ""))
 

@@ -41,6 +41,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, reduce_config
 from repro.data.pipeline import synthetic_lm_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import get_model
 from repro.serving import Engine
@@ -172,7 +173,9 @@ def _run_engine(cfg, params, reqs, *, mor, mor_mode, n_slots, max_len,
     # eng.run() ends with a blocking flush, so these walls include the
     # device drain (unlike counters["wall_s"], which is host dispatch
     # time only — the hot loop never syncs).
+    t0 = time.time()
     eng.run(list(reqs))
+    warmup = time.time() - t0
     wall = float("inf")
     for _ in range(3):
         eng.reset_counters()
@@ -183,6 +186,11 @@ def _run_engine(cfg, params, reqs, *, mor, mor_mode, n_slots, max_len,
     results = {rid - base: toks for rid, toks in results.items()}
     rep = eng.report()
     rep["requests_finished"] = len(results)      # the timed pass only
+    # generated tokens per request, in trace order (a finished request
+    # holds exactly its generation length)
+    rep["request_tokens"] = [list(map(int, results[r]))
+                             for r in sorted(results)]
+    rep["warmup_wall_s"] = warmup                # compiles + one pass
     total = rep["prefill_tokens"] + rep["decode_tokens"]
     rep["tokens_per_s"] = total / wall
     rep["decode_tokens_per_s"] = rep["decode_tokens"] / wall
@@ -320,6 +328,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out-json", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -338,6 +347,7 @@ def main(argv=None):
 
     mor = None
     report = {"arch": cfg.name, "mor_mode": args.mor}
+    t_cal = time.time()
     if args.mor != "dense":
         from repro.core.deploy import (calibrate_hybrid, calibrate_lm,
                                        calibrate_moe)
@@ -363,6 +373,7 @@ def main(argv=None):
             params, mor, cal = calibrate_lm(params, cfg, api.forward,
                                             batches(), args.calib_steps)
         report["calibration"] = cal
+        report["calibration_s"] = time.time() - t_cal
 
     pmin = args.prompt_min or args.prompt_len
     pmax = args.prompt_max or args.prompt_len
@@ -490,6 +501,7 @@ def main(argv=None):
             np.mean(np.asarray(results[r]) == np.asarray(results_d[r]))
             for r in results_d])
         report["dense_tokens_per_s"] = rep_d["tokens_per_s"]
+        report["dense_request_tokens"] = rep_d["request_tokens"]
         report["token_agreement_vs_dense"] = float(agree)
         print(f"[serve] dense baseline: {rep_d['tokens_per_s']:.1f} tok/s; "
               f"token agreement {agree:.3f}")

@@ -27,6 +27,7 @@ from repro.data.pipeline import make_batch
 from repro.distributed.fault_tolerance import StragglerMonitor
 from repro.distributed.sharding_rules import (activation_context,
                                               batch_sharding, param_sharding)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import init_train_state, make_train_step
 from repro.models import get_model
@@ -51,6 +52,7 @@ def main(argv=None):
                     help="run MoR calibration after training")
     ap.add_argument("--out-json", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

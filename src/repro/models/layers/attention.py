@@ -212,7 +212,7 @@ def _tp_flash_decode(q, k, v, kv_pos, pos, window: int):
     GSPMD's derived strategy, which all-gathered the sharded KV
     (measured 16 GB/step on qwen2-7b decode_32k)."""
     from repro.distributed.sharding_rules import _TLS
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     ctx = getattr(_TLS, "ctx", None)
     if ctx is None:
@@ -253,7 +253,7 @@ def _tp_flash_decode(q, k, v, kv_pos, pos, window: int):
         body, mesh=mesh,
         in_specs=(P(b_spec), P(b_spec, "model"), P(b_spec, "model"),
                   P(None, "model")),
-        out_specs=P(b_spec), check_rep=False,
+        out_specs=P(b_spec), check_vma=False,
     )(qf, k, v, kv_pos[None, :])
     return out.reshape(B, 1, q.shape[2], q.shape[-1])
 

@@ -88,7 +88,7 @@ def moe_apply_a2a(params: Dict, cfg: ModelConfig, x, *,
     Router compute is replicated across the model axis (negligible).
     Capacity is static: C_loc = cf * T_loc * k / E."""
     from repro.distributed.sharding_rules import _TLS
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     ctx = getattr(_TLS, "ctx", None)
     if ctx is None:
@@ -229,7 +229,7 @@ def moe_apply_a2a(params: Dict, cfg: ModelConfig, x, *,
         in_specs=(P(dp_spec), P(), up_spec,
                   up_spec if glu else P(), down_spec, em_spec, cap_spec),
         out_specs=(P(dp_spec), P()),
-        check_rep=False,
+        check_vma=False,
     )(xf, params["router"].astype(dt), params["w_up"].astype(dt),
       gate.astype(dt) if glu else jnp.zeros((), dt),
       params["w_down"].astype(dt), em_arg, cap_arg)

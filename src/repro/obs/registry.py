@@ -166,12 +166,12 @@ class Histogram(_Family):
         s = self._series.get(_label_key(self.label_names, labels))
         if s is None or s.count == 0:
             return {"count": 0}
-        return {"count": s.count, "sum": round(s.sum, 6),
-                "mean": round(s.sum / s.count, 6),
-                "min": round(s.vmin, 6), "max": round(s.vmax, 6),
-                "p50": round(self.quantile(0.50, **labels), 6),
-                "p90": round(self.quantile(0.90, **labels), 6),
-                "p99": round(self.quantile(0.99, **labels), 6)}
+        # exact values: rounding here put quantiles outside [min, max]
+        return {"count": s.count, "sum": s.sum, "mean": s.sum / s.count,
+                "min": s.vmin, "max": s.vmax,
+                "p50": self.quantile(0.50, **labels),
+                "p90": self.quantile(0.90, **labels),
+                "p99": self.quantile(0.99, **labels)}
 
 
 class MetricsRegistry:

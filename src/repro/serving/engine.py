@@ -91,18 +91,23 @@ class Engine:
         self.chunk = chunk or cfg.serve_chunk
         self.n_slots = n_slots
         self.max_len = max_len
-        self.mor = self._attach(capacities)
-        self.capacities = capacities
         self.layout = layout
         self.mesh = None
+        if layout == "paged-sharded":
+            if mesh is None:
+                from repro.launch.mesh import make_page_mesh
+                mesh = make_page_mesh()
+            # params and plans are replicated on every page shard ONCE
+            # here, not re-sent from device 0 on every dispatch
+            from repro.serving.mesh import replicate
+            self.mesh = mesh
+            self.params = replicate(params, mesh)
+        self.mor = self._attach(capacities)
+        self.capacities = capacities
         if layout in ("paged", "paged-sharded"):
             n_shards = 1
             if layout == "paged-sharded":
-                if mesh is None:
-                    from repro.launch.mesh import make_page_mesh
-                    mesh = make_page_mesh()
                 from repro.distributed.sharding_rules import PAGE_AXIS
-                self.mesh = mesh
                 n_shards = mesh.shape[PAGE_AXIS]
             self.pool: Optional[kv_pool.PagedPool] = kv_pool.PagedPool(
                 cfg, n_slots, max_len, chunk=self.chunk, page=page,
@@ -513,8 +518,12 @@ class Engine:
         if capacities is not None:
             gmap = mor_group_map(self.cfg)
             caps = {gmap.get(k, k): v for k, v in capacities.items()}
-        return attach_plans(self.raw_mor, self.cfg, self.mor_mode,
-                            capacities=caps)
+        plans = attach_plans(self.raw_mor, self.cfg, self.mor_mode,
+                             capacities=caps)
+        if self.mesh is not None:
+            from repro.serving.mesh import replicate
+            plans = replicate(plans, self.mesh)
+        return plans
 
     @staticmethod
     def _step_impl(cfg, api, mor_mode, temperature, top_k, mspec,

@@ -1092,16 +1092,18 @@ class PagedPool:
         ``W * page``, which is only sound while no slot has wrapped —
         ``pos + n_valid`` is clamped to ``ring`` so any wrap (windowed
         rings) forces the full width.  The result is bucketed to the
-        next multiple of 4 blocks (capped at ``n_blocks``) — coarse
-        enough that the engine compiles O(n_blocks / 4) step variants,
-        fine enough that the attend width tracks the longest live
-        sequence instead of snapping to the full ring."""
+        next power of two blocks (capped at ``n_blocks``) — coarse
+        enough that the engine compiles O(log n_blocks) step variants
+        (each a whole-model program: at published widths every variant
+        costs seconds of compile), fine enough that the attend width
+        stays within 2x of the longest live sequence instead of
+        snapping to the full ring."""
         if not self.has_kv:
             return None
         need = int(np.minimum(self.pos + np.asarray(n_valid, np.int64),
                               self.ring).max(initial=0))
         w = max(1, -(-need // self.page))
-        return min(-(-w // 4) * 4, self.n_blocks)
+        return min(1 << (w - 1).bit_length(), self.n_blocks)
 
     def maybe_snapshot(self, slot: int, prompt: np.ndarray,
                        offset: int) -> None:

@@ -32,15 +32,15 @@ from __future__ import annotations
 from typing import Dict
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.decode_attention import page_shard_context
 from repro.distributed.sharding_rules import PAGE_AXIS
 from repro.serving import kv_pool
 
-__all__ = ["cache_partition_specs", "shard_cache", "sharded_apply",
-           "make_sharded_step", "make_sharded_shadow_step"]
+__all__ = ["cache_partition_specs", "shard_cache", "replicate",
+           "sharded_apply", "make_sharded_step", "make_sharded_shadow_step"]
 
 
 def cache_partition_specs(cache: Dict) -> Dict:
@@ -85,6 +85,12 @@ def shard_cache(cache: Dict, mesh, specs: Dict = None) -> Dict:
                   lambda a, s: jax.device_put(a, NamedSharding(mesh, s)))
 
 
+def replicate(tree, mesh):
+    """Place every array leaf of ``tree`` on all mesh devices (the
+    ``P()`` operands of the sharded step)."""
+    return jax.device_put(tree, NamedSharding(mesh, P()))
+
+
 def sharded_apply(mesh, specs: Dict):
     """The standalone (overflow-round) cache-ops apply as a shard_map
     step: each shard applies its own ops row to its local page range.
@@ -98,7 +104,7 @@ def sharded_apply(mesh, specs: Dict):
                 return kv_pool.apply_cache_ops(cache, ops[0], *pads)
 
         return shard_map(body, mesh=mesh, in_specs=(specs, P(PAGE_AXIS)),
-                         out_specs=specs, check_rep=False)(cache, ops)
+                         out_specs=specs, check_vma=False)(cache, ops)
 
     return jax.jit(fn, donate_argnums=(0,), static_argnums=(2,))
 
@@ -145,7 +151,7 @@ def make_sharded_step(body, mesh, cache: Dict):
             in_specs=(P(), P(), specs, P(), P(), P(), P(), P(),
                       P(PAGE_AXIS), P(PAGE_AXIS)),
             out_specs=(P(), P(), specs, P(), P(PAGE_AXIS)),
-            check_rep=False,
+            check_vma=False,
         )(params, mor, cache, tokens, n_valid, use_pending, pending, key,
           ops, metrics)
 
@@ -177,7 +183,7 @@ def make_sharded_shadow_step(body, mesh, cache: Dict):
             in_specs=(P(), P(), specs, P(), P(), P(), P(),
                       P(PAGE_AXIS), P(PAGE_AXIS)),
             out_specs=P(PAGE_AXIS),
-            check_rep=False,
+            check_vma=False,
         )(params, mor, cache, tokens, n_valid, use_pending, pending,
           ops, metrics)
 

@@ -23,7 +23,8 @@ from repro.launch.steps import make_train_step, make_serve_step
 from repro.models import get_model, param_shapes, cache_shapes
 from repro.optim import OptConfig, adamw_init
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = reduce_config(get_config("granite-3-2b")).replace(
     d_model=64, d_ff=128, n_heads=4, n_kv_heads=4, d_head=16)
 p_sds = param_shapes(cfg)
@@ -60,6 +61,38 @@ def test_small_mesh_lower_compile():
     assert r.returncode == 0, r.stderr[-3000:]
     assert "TRAIN_LOWER_OK" in r.stdout
     assert "DECODE_LOWER_OK" in r.stdout
+
+
+_CACHE_PROBE = r"""
+import os, jax, jax.numpy as jnp
+from repro.launch.compile_cache import enable_compile_cache
+where = enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.jit(lambda x: x * 3 + 1).lower(jnp.arange(7.0)).compile()
+print(where)
+print(len(os.listdir(where)))
+"""
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_location(env_dir, tmp_path):
+    """The persistent cache lands in $JAX_COMPILATION_CACHE_DIR when it is
+    set, else at the one fixed, git-ignored path in the checkout."""
+    from repro.launch.compile_cache import DEFAULT_DIR
+    env = dict(os.environ, PYTHONPATH="src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = DEFAULT_DIR
+    if env_dir:
+        want = tmp_path / "cache"
+        env["JAX_COMPILATION_CACHE_DIR"] = str(want)
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE],
+                       capture_output=True, text=True, env=env,
+                       cwd=os.getcwd(), timeout=120)
+    assert r.returncode == 0, r.stderr[-3000:]
+    where, n_entries = r.stdout.split()[-2:]
+    assert where == str(want) and int(n_entries) > 0
+    ignored = (DEFAULT_DIR.parent / ".gitignore").read_text().split()
+    assert f"{DEFAULT_DIR.name}/" in ignored
 
 
 def test_parse_collectives_factors():

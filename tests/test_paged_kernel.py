@@ -140,8 +140,10 @@ def test_gqa_partial_stats_merge_to_full():
                                atol=1e-5, rtol=1e-5)
 
 
-def test_mla_kernel_matches_ref():
-    ql, qe, ck, cpe, cp, tbl, qpos = _mla_case(3)
+@pytest.mark.parametrize("h,C", [(3, 2), (128, 4)])
+def test_mla_kernel_matches_ref(h, C):
+    """(128, 4) spans two MLA_ROW_BLOCK row blocks of the grid."""
+    ql, qe, ck, cpe, cp, tbl, qpos = _mla_case(3, h=h, C=C)
     scale = (ql.shape[-1] + qe.shape[-1]) ** -0.5
     out = pk.mla_paged_flash(ql, qe, ck, cpe, cp, tbl, qpos, scale=scale,
                              interpret=True)
@@ -178,7 +180,7 @@ _TRACE_KEY = {"deepseek-v2-236b": "mla"}
                                   "deepseek-v2-236b", "mixtral-8x7b",
                                   "zamba2-7b"])
 def test_engine_kernel_matches_jnp(arch, monkeypatch):
-    """REPRO_PAGED_KERNEL=1 must be token-identical to the jnp gather
+    """The paged kernel path must be token-identical to the jnp gather
     fallback on ragged prompts (3-13 toks vs chunk 8 → partial final
     chunks); mixtral keeps sliding_window=16 and generates past it, so
     its ring wraps through the kernel's window mask.  The jnp path is
@@ -193,10 +195,10 @@ def test_engine_kernel_matches_jnp(arch, monkeypatch):
              int(rng.integers(3, 6))) for _ in range(3)]
     if arch == "mixtral-8x7b":          # force a ring wrap past window=16
         reqs[0] = (rng.integers(0, cfg.vocab_size, size=22), 6)
-    monkeypatch.setenv("REPRO_PAGED_KERNEL", "0")
+    monkeypatch.setattr(pk, "enabled", lambda: False)
     res_jnp = Engine(cfg, params, n_slots=2, max_len=64,
                      layout="paged").run(list(reqs))
-    monkeypatch.setenv("REPRO_PAGED_KERNEL", "1")
+    monkeypatch.setattr(pk, "enabled", lambda: True)
     pk.reset_kernel_traces()
     res_k = Engine(cfg, params, n_slots=2, max_len=64,
                    layout="paged").run(list(reqs))
@@ -217,10 +219,10 @@ def test_engine_kernel_prefix_cache_on_off(monkeypatch):
     reqs = [(np.concatenate([prefix,
                              rng.integers(0, cfg.vocab_size, size=4)]), 4)
             for _ in range(3)]
-    monkeypatch.setenv("REPRO_PAGED_KERNEL", "0")
+    monkeypatch.setattr(pk, "enabled", lambda: False)
     res_jnp = Engine(cfg, params, n_slots=2, max_len=64,
                      prefix_cache=False).run(list(reqs))
-    monkeypatch.setenv("REPRO_PAGED_KERNEL", "1")
+    monkeypatch.setattr(pk, "enabled", lambda: True)
     cold = Engine(cfg, params, n_slots=2, max_len=64,
                   prefix_cache=False).run(list(reqs))
     warm_eng = Engine(cfg, params, n_slots=2, max_len=64)
@@ -235,13 +237,13 @@ def test_engine_kernel_prefix_cache_on_off(monkeypatch):
 _SHARDED_KERNEL_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-os.environ["REPRO_PAGED_KERNEL"] = "1"
 import jax, numpy as np
 from repro.configs import get_config, reduce_config
 from repro.models import get_model
 from repro.serving import Engine
 from repro.launch.mesh import make_page_mesh
 from repro.kernels import paged_attention as pk
+pk.enabled = lambda: True            # the kernel path, as on TPU
 
 cfg = reduce_config(get_config("granite-3-2b"))
 api = get_model(cfg)
@@ -261,7 +263,7 @@ print("SHARDED_KERNEL_OK")
 
 
 def test_paged_sharded_kernel_multidevice():
-    """The sharded decode path with REPRO_PAGED_KERNEL=1 (partial flash
+    """The sharded decode path with the paged kernel on (partial flash
     stats + one flash_merge per layer) is token-identical to the
     single-device kernel engine, on 4 forced host devices in a
     subprocess (jax device count locks at first init).  Kept to one
