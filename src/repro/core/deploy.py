@@ -22,6 +22,7 @@ from repro.core.calibration import (finalize_regression, init_accumulator,
 from repro.core.clustering import cluster_layer
 from repro.core.executor import MoRExecutionPlan
 from repro.core.policy import build_mor_layer
+from repro.obs.tracer import span
 
 
 def _stack_mor(layers: List[Dict]) -> Dict:
@@ -129,73 +130,87 @@ def calibrate_lm(params: Dict, cfg: ModelConfig, forward: Callable,
     """Calibrate a scan-stacked LM (dense/ssm/audio families).
 
     -> (params with permuted FFN weights, mor pytree {layer_key: stacked},
-        report dict with Pearson stats)."""
+        report dict with Pearson stats).  Spans (``repro.obs.tracer``): a
+    ``calibrate`` root with ``calibrate.taps`` (the forward passes and
+    the regression, ending when the host reads it back),
+    ``calibrate.cluster`` (per-layer clustering and predictor build) and
+    ``calibrate.permute`` (folding the permutations into the weights)."""
     L = cfg.n_layers
-    # locate the target weight stack: mlp (w_gate|w_up) or rwkv cm w_up
-    lp = params[layer_key]
-    if "mlp" in lp:
-        w_stack = lp["mlp"].get("w_gate", lp["mlp"]["w_up"])
-    else:
-        w_stack = lp["cm"]["w_up"]
-    N = w_stack.shape[-1]
+    with span("calibrate", layers=L, batches=n_batches):
+        # locate the target weight stack: mlp (w_gate|w_up) or rwkv cm
+        # w_up
+        lp = params[layer_key]
+        if "mlp" in lp:
+            w_stack = lp["mlp"].get("w_gate", lp["mlp"]["w_up"])
+        else:
+            w_stack = lp["cm"]["w_up"]
+        N = w_stack.shape[-1]
 
-    acc = jax.vmap(lambda _: init_accumulator(N))(jnp.arange(L))
-    upd = jax.jit(jax.vmap(update_accumulator))
-    fwd = jax.jit(lambda p, b: forward(p, cfg, b, with_taps=True)[1]["taps"])
-    seen = 0
-    for batch in batches:
-        taps = fwd(params, batch)
-        acc = upd(acc, taps["p_bin"], taps["p_base"])
-        seen += 1
-        if seen >= n_batches:
-            break
-    m, b, c = jax.vmap(finalize_regression)(acc)
-    m, b, c = np.asarray(m), np.asarray(b), np.asarray(c)
+        # the jitted forward passes and accumulator, up to the host
+        # reading the regression back (which waits for the device)
+        with span("calibrate.taps"):
+            acc = jax.vmap(lambda _: init_accumulator(N))(jnp.arange(L))
+            upd = jax.jit(jax.vmap(update_accumulator))
+            fwd = jax.jit(lambda p, b: forward(p, cfg, b,
+                                               with_taps=True)[1]["taps"])
+            seen = 0
+            for batch in batches:
+                taps = fwd(params, batch)
+                acc = upd(acc, taps["p_bin"], taps["p_base"])
+                seen += 1
+                if seen >= n_batches:
+                    break
+            m, b, c = jax.vmap(finalize_regression)(acc)
+            m, b, c = np.asarray(m), np.asarray(b), np.asarray(c)
 
-    mor_layers = []
-    w_np = np.asarray(w_stack, np.float32)
-    for l in range(L):
-        cl = cluster_layer(w_np[l], cfg.mor.max_cluster_angle)
-        mor_layers.append(build_mor_layer(m[l], b[l], c[l], cl, cfg.mor))
-    mor_stack = _stack_mor(mor_layers)
+        with span("calibrate.cluster"):
+            mor_layers = []
+            w_np = np.asarray(w_stack, np.float32)
+            for l in range(L):
+                cl = cluster_layer(w_np[l], cfg.mor.max_cluster_angle)
+                mor_layers.append(build_mor_layer(m[l], b[l], c[l], cl,
+                                                  cfg.mor))
+            mor_stack = _stack_mor(mor_layers)
 
-    # fold permutations into the weights (offline, zero runtime cost)
-    perm = np.asarray(mor_stack["perm"])          # (L, N)
-    new_params = jax.tree_util.tree_map(lambda x: x, params)  # shallow copy
+        # fold permutations into the weights (offline, zero runtime cost)
+        with span("calibrate.permute"):
+            perm = np.asarray(mor_stack["perm"])          # (L, N)
+            new_params = jax.tree_util.tree_map(lambda x: x, params)
 
-    def permute_stack(w, axis):
-        w = np.asarray(w)
-        out = np.empty_like(w)
-        for l in range(L):
-            idx = perm[l]
-            out[l] = np.take(w[l], idx, axis=axis - 1)
-        return jnp.asarray(out)
+            def permute_stack(w, axis):
+                w = np.asarray(w)
+                out = np.empty_like(w)
+                for l in range(L):
+                    idx = perm[l]
+                    out[l] = np.take(w[l], idx, axis=axis - 1)
+                return jnp.asarray(out)
 
-    if "mlp" in lp:
-        mlp = dict(lp["mlp"])
-        if "w_gate" in mlp:
-            mlp["w_gate"] = permute_stack(mlp["w_gate"], 2)
-        mlp["w_up"] = permute_stack(mlp["w_up"], 2)
-        mlp["w_down"] = permute_stack(mlp["w_down"], 1)
-        new_lp = dict(lp)
-        new_lp["mlp"] = mlp
-        new_params[layer_key] = new_lp
-    else:
-        cm = dict(lp["cm"])
-        cm["w_up"] = permute_stack(cm["w_up"], 2)
-        cm["w_down"] = permute_stack(cm["w_down"], 1)
-        new_lp = dict(lp)
-        new_lp["cm"] = cm
-        new_params[layer_key] = new_lp
+            if "mlp" in lp:
+                mlp = dict(lp["mlp"])
+                if "w_gate" in mlp:
+                    mlp["w_gate"] = permute_stack(mlp["w_gate"], 2)
+                mlp["w_up"] = permute_stack(mlp["w_up"], 2)
+                mlp["w_down"] = permute_stack(mlp["w_down"], 1)
+                new_lp = dict(lp)
+                new_lp["mlp"] = mlp
+                new_params[layer_key] = new_lp
+            else:
+                cm = dict(lp["cm"])
+                cm["w_up"] = permute_stack(cm["w_up"], 2)
+                cm["w_down"] = permute_stack(cm["w_down"], 1)
+                new_lp = dict(lp)
+                new_lp["cm"] = cm
+                new_params[layer_key] = new_lp
 
-    report = {
-        "pearson_mean": float(c.mean()),
-        "pearson_frac_above_T": float((c > cfg.mor.corr_threshold).mean()),
-        "n_proxies_mean": float(np.mean([
-            len(np.unique(np.asarray(ml["proxy_slot"])))
-            for ml in mor_layers])),
-        "enabled_frac": float(np.asarray(mor_stack["enable"]).mean()),
-    }
+        report = {
+            "pearson_mean": float(c.mean()),
+            "pearson_frac_above_T": float(
+                (c > cfg.mor.corr_threshold).mean()),
+            "n_proxies_mean": float(np.mean([
+                len(np.unique(np.asarray(ml["proxy_slot"])))
+                for ml in mor_layers])),
+            "enabled_frac": float(np.asarray(mor_stack["enable"]).mean()),
+        }
     return new_params, {layer_key: mor_stack}, report
 
 

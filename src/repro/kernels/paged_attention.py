@@ -54,6 +54,17 @@ NEG_INF = -1e30
 MLA_ROW_BLOCK = 256
 
 
+def _named_jit(name: str, **jit_kw):
+    """``jax.jit`` under ``name``.  A Pallas kernel's custom call takes
+    the name of the innermost jit that issues it, so a device trace
+    shows the kernel as ``name`` and not as the serving step around it
+    (an unnamed ``partial``, ``_unknown_`` in the trace)."""
+    def wrap(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn, **jit_kw)
+    return wrap
+
+
 def enabled() -> bool:
     """Kernel-vs-jnp choice for the PAGED ATTEND serving paths: the
     fused kernel on TPU, the jnp gather fallback elsewhere
@@ -203,13 +214,23 @@ def gqa_paged_flash(q, kpool, vpool, ppool, block_table, qpos, *,
     and every operand keeps its last two dims whole, so Mosaic needs no
     relayout at any chunk width."""
     _bump_trace("gqa")
+    tbl, live = _live_tables(block_table, lo, n_local)
+    return _gqa_paged_flash(
+        q, kpool, vpool, ppool, tbl, live, qpos, window=window,
+        partial=partial,
+        interpret=_interpret() if interpret is None else interpret)
+
+
+@_named_jit("gqa_paged_flash",
+            static_argnames=("window", "partial", "interpret"))
+def _gqa_paged_flash(q, kpool, vpool, ppool, tbl, live, qpos, *,
+                     window: int, partial: bool, interpret: bool):
     B, C, H, D = q.shape
     n_pages, page, hkv = kpool.shape[:3]
     Dv = vpool.shape[-1]
     G = H // hkv
     R = G * C
-    n_blocks = block_table.shape[1]
-    tbl, live = _live_tables(block_table, lo, n_local)
+    n_blocks = tbl.shape[1]
     # rows ordered (g, c) per kv head; each row carries its query position
     qh = q.reshape(B, C, hkv, G, D).transpose(0, 2, 3, 1, 4).reshape(
         B, hkv, R, D)
@@ -251,7 +272,7 @@ def gqa_paged_flash(q, kpool, vpool, ppool, block_table, qpos, *,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(tbl, live, qp, qh, kpool, vpool, ppool.reshape(n_pages, 1, page))
     if partial:
         m, l, a = out
@@ -314,13 +335,24 @@ def mla_paged_flash(q_lat, q_pe, ck_pool, cpe_pool, cp_pool, block_table,
     queries as (h*C) rows, cut into row blocks of at most
     ``MLA_ROW_BLOCK`` to bound the VMEM accumulator."""
     _bump_trace("mla")
+    tbl, live = _live_tables(block_table, lo, n_local)
+    return _mla_paged_flash(
+        q_lat, q_pe, ck_pool, cpe_pool, cp_pool, tbl, live, qpos,
+        scale=scale, partial=partial,
+        interpret=_interpret() if interpret is None else interpret)
+
+
+@_named_jit("mla_paged_flash",
+            static_argnames=("scale", "partial", "interpret"))
+def _mla_paged_flash(q_lat, q_pe, ck_pool, cpe_pool, cp_pool, tbl, live,
+                     qpos, *, scale: float, partial: bool,
+                     interpret: bool):
     B, C, h, kr = q_lat.shape
     rd = q_pe.shape[-1]
     n_pages, page = ck_pool.shape[:2]
-    n_blocks = block_table.shape[1]
+    n_blocks = tbl.shape[1]
     R = h * C
     rb = MLA_ROW_BLOCK if R % MLA_ROW_BLOCK == 0 else R
-    tbl, live = _live_tables(block_table, lo, n_local)
     ql = q_lat.transpose(0, 2, 1, 3).reshape(B, R, kr)     # rows (h, c)
     qe = q_pe.transpose(0, 2, 1, 3).reshape(B, R, rd)
     qp = jnp.tile(qpos.astype(jnp.int32), (1, h))[..., None]    # (B, R, 1)
@@ -360,7 +392,7 @@ def mla_paged_flash(q_lat, q_pe, ck_pool, cpe_pool, cp_pool, block_table,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(tbl, live, qp, ql, qe, ck_pool, cpe_pool,
       cp_pool.reshape(n_pages, 1, page))
     if partial:

@@ -6,8 +6,10 @@ One :class:`Observability` bundle carries the three pieces:
   (counters / gauges / histograms with labels; JSON snapshot +
   Prometheus text exposition).
 - ``tracer`` — :class:`~repro.obs.tracer.Tracer` (per-request and
-  per-dispatch spans, TTFT / inter-token latency histograms, Chrome
-  trace / Perfetto export).  ``None`` when tracing is disabled.
+  per-dispatch events, TTFT / inter-token latency histograms stamped on
+  delivery, the host spans' counters, Chrome trace / Perfetto export).
+  ``None`` when tracing is disabled.  The host spans themselves
+  (``repro.obs.tracer.span``) are always on.
 - ``device_metrics`` flag — when True the engine threads a packed
   int32 :class:`~repro.obs.device.DeviceMetricsSpec` block through the
   compiled step and drains it only at flush boundaries.
@@ -39,15 +41,11 @@ class Observability:
     are disambiguated by labels (layout, group, shard)."""
 
     def __init__(self, registry: MetricsRegistry = None, *,
-                 device_metrics: bool = True, tracing: bool = True,
-                 jax_annotations: bool = False):
+                 device_metrics: bool = True, tracing: bool = True):
         self.registry = registry if registry is not None \
             else MetricsRegistry()
         self.device_metrics = device_metrics
-        self.jax_annotations = jax_annotations
-        self.tracer = Tracer(self.registry,
-                             jax_annotations=jax_annotations) \
-            if tracing else None
+        self.tracer = Tracer(self.registry) if tracing else None
 
     def snapshot(self) -> dict:
         out = {"metrics": self.registry.snapshot()}

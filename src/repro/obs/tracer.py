@@ -1,32 +1,161 @@
-"""Span-based request tracer for the serving engine.
+"""Host spans and the request tracer.
 
-The engine's hot loop is host-synchronous per dispatch (one
-``step()`` = one compiled-step launch), so the tracer records spans
-from the host dispatch timeline: for each request admit → prefill
-chunks → decode emits → finish, and for the engine a span per
-dispatch.  TTFT is measured submit → end of the dispatch that emitted
-the request's first token; inter-token latency is the gap between the
-ends of consecutive emitting dispatches.  Both are host-timeline
-approximations (a dispatch emits tokens for many slots at once), which
-is exactly the granularity the scheduler can act on.
+**Spans.** ``with span(name, **args):`` is the one span API of the
+process.  Each span records its name, start and end on
+``time.perf_counter`` (the benchmark harness's clock), the id of the
+span it opened inside, and its args, into a bounded process-wide ring
+(``RING_SIZE`` entries, oldest dropped and counted) that is always on.
+Each span is also a ``jax.profiler.TraceAnnotation``, so a profiler
+trace holds the same spans on the profiler's clock, nested inside
+whatever annotations enclose them.  ``host_spans()`` returns the ring.
+The ring is process-wide because its users are: calibration runs with
+no engine, and ``jax.profiler`` traces the whole process.  Spans nest by
+the order they open and close on the thread that runs them (the
+engine's loop is single-threaded).  Every executable JAX builds adds a
+``jax.compile`` span (from ``jax.monitoring``'s backend compile event,
+args ``fun``), inside the span that was open when it was built.
+
+**Tracer** (``Tracer``, opt-in through ``repro.obs.Observability``):
+per-request events from the engine's hooks, for each request queued →
+prefill chunks → decode emits → finish.  TTFT is submit → the host
+receiving the request's first token (``on_deliver``, stamped when the
+engine's token fetch returns); inter-token latency is the gap between
+consecutive tokens of a request reaching the host (tokens that arrive
+together are 0 apart).  ``mirror_spans`` mirrors the ring into the
+registry (``repro_engine_span_seconds_total`` / ``repro_engine_spans_total``
+by span name) at the engine's flush.
 
 Export is Chrome-trace JSON (``{"traceEvents": [...]}`` with "ph":"X"
 complete events) — loadable in Perfetto / chrome://tracing.  Span
 events per request live on a per-slot track so concurrent requests
-stack visually the way they share slots physically.
-
-Optionally (``jax_annotations=True``) each dispatch is wrapped in a
-``jax.profiler.TraceAnnotation`` so the spans line up with XLA events
-in a device profile; off by default to keep the overhead budget.
+stack visually the way they share slots physically; the ring's spans
+recorded since the tracer's reset ride a "host" track.
 """
 from __future__ import annotations
 
 import json
 import time
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from itertools import islice
+from typing import Deque, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
-__all__ = ["Tracer", "validate_chrome_trace"]
+import jax
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "validate_chrome_trace", "span", "host_spans",
+           "spans_dropped", "Span", "RING_SIZE"]
+
+RING_SIZE = 1 << 16
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_now = time.perf_counter
+_tracing = TraceAnnotation.is_enabled     # a profiler trace is recording
+
+
+class Span(NamedTuple):
+    id: int
+    parent: int              # 0: a root
+    name: str
+    t0: float                # seconds, perf_counter timebase
+    t1: float
+    args: Dict
+
+
+class _Ring:
+    """The process's spans: a bounded ring, the ids of the spans open
+    now (innermost last), and counts of spans recorded and dropped."""
+
+    def __init__(self, size: int):
+        self.spans: Deque[Span] = deque(maxlen=size)
+        self.open: List[int] = []
+        self.next_id = 1
+        self.added = 0
+        self.dropped = 0
+
+    def add(self, sp: Span) -> None:
+        if len(self.spans) == self.spans.maxlen:
+            self.dropped += 1
+        self.spans.append(sp)
+        self.added += 1
+
+    def since(self, n_added: int) -> List[Span]:
+        """Spans recorded after the ring had recorded ``n_added``, in
+        the order they closed (those since dropped excepted)."""
+        k = min(self.added - n_added, len(self.spans))
+        return list(islice(reversed(self.spans), k))[::-1]
+
+
+_RING = _Ring(RING_SIZE)
+
+
+_new_span = tuple.__new__            # Span(...) without its Python __new__
+
+
+class span:
+    """``with span("engine.step", kind="decode") as sp:`` — one host
+    span, recorded into the ring when it closes (``sp.args`` may be
+    added to until then) and, while a profiler trace is recording, a
+    ``jax.profiler.TraceAnnotation`` (which records nothing when made
+    outside a trace, so none is made then)."""
+
+    __slots__ = ("name", "args", "_id", "_parent", "_t0", "_ann")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+
+    def __enter__(self) -> "span":
+        r = _RING
+        self._id = i = r.next_id
+        r.next_id = i + 1
+        self._parent = r.open[-1] if r.open else 0
+        r.open.append(i)
+        if _tracing():
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._t0 = _now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        t1 = _now()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        r = _RING
+        r.open.pop()
+        # _Ring.add, inlined: this runs once per span
+        if len(r.spans) == RING_SIZE:
+            r.dropped += 1
+        r.spans.append(_new_span(Span, (self._id, self._parent, self.name,
+                                        self._t0, t1, self.args)))
+        r.added += 1
+
+
+def host_spans() -> Deque[Span]:
+    """The ring itself, oldest first (a test may ``clear()`` it)."""
+    return _RING.spans
+
+
+def spans_dropped() -> int:
+    """Spans the ring has dropped to stay at ``RING_SIZE``."""
+    return _RING.dropped
+
+
+def _on_compile(event: str, duration: float, fun_name: str = "",
+                **_) -> None:
+    if event != COMPILE_EVENT:
+        return
+    r = _RING
+    t1 = _now()
+    i = r.next_id
+    r.next_id = i + 1
+    r.add(Span(i, r.open[-1] if r.open else 0, "jax.compile",
+               t1 - duration, t1, {"fun": fun_name}))
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
 
 # inter-token latencies at reduced dims are sub-ms; extend the default
 # latency buckets downward for ITL
@@ -42,10 +171,11 @@ class _Req:
     t_submit: float
     t_admit: Optional[float] = None
     t_first_token: Optional[float] = None
-    t_last_emit: Optional[float] = None
+    t_last_token: Optional[float] = None
     t_finish: Optional[float] = None
     slot: Optional[int] = None
     n_tokens: int = 0
+    finished: bool = False        # its last token was dispatched
 
 
 @dataclass
@@ -58,36 +188,43 @@ class _Event:
     args: Dict = field(default_factory=dict)
 
 
-class Tracer:
-    """Collects dispatch + request spans; drains into registry
-    histograms and a Chrome-trace event list."""
+def _jsonable(v):
+    return v.tolist() if hasattr(v, "tolist") else v
 
-    def __init__(self, registry=None, jax_annotations: bool = False,
-                 max_events: int = 100_000):
+
+class Tracer:
+    """Collects dispatch + request events on top of the span ring;
+    drains into registry histograms, the span counters and a
+    Chrome-trace event list."""
+
+    def __init__(self, registry=None, max_events: int = 100_000):
         self.registry = registry
-        self.jax_annotations = jax_annotations
         self.max_events = max_events
-        self.reset()
         if registry is not None:
             self._h_ttft = registry.histogram(
                 "repro_serving_ttft_seconds",
-                "time from submit to first emitted token",
+                "time from submit to the first token reaching the host",
                 buckets=TTFT_BUCKETS)
             self._h_itl = registry.histogram(
                 "repro_serving_itl_seconds",
-                "inter-token latency between emitting dispatches",
+                "gap between consecutive tokens of a request reaching "
+                "the host",
                 buckets=ITL_BUCKETS)
             self._h_queue = registry.histogram(
                 "repro_serving_queue_wait_seconds",
                 "time from submit to slot admission",
                 buckets=TTFT_BUCKETS)
-            self._h_dispatch = registry.histogram(
-                "repro_serving_dispatch_seconds",
-                "wall time of one engine dispatch", ("kind",),
-                buckets=ITL_BUCKETS)
+            self._c_span_s = registry.counter(
+                "repro_engine_span_seconds_total",
+                "host seconds inside each span (repro.obs.tracer.span)",
+                ("span",))
+            self._c_span_n = registry.counter(
+                "repro_engine_spans_total",
+                "spans recorded (repro.obs.tracer.span)", ("span",))
         else:
-            self._h_ttft = self._h_itl = None
-            self._h_queue = self._h_dispatch = None
+            self._h_ttft = self._h_itl = self._h_queue = None
+            self._c_span_s = self._c_span_n = None
+        self.reset()
 
     def reset(self) -> None:
         self._reqs: Dict[int, _Req] = {}
@@ -97,33 +234,27 @@ class Tracer:
         self._n_restore = 0
         self._n_drift = 0
         self._dropped = 0
-        # the tracer owns its latency histograms: a reset boundary (the
-        # engine's reset_counters between timed passes) zeroes them too,
-        # so exported quantiles describe the LAST pass, not the compile-
-        # heavy warmup
-        for h in (getattr(self, "_h_ttft", None),
-                  getattr(self, "_h_itl", None),
-                  getattr(self, "_h_queue", None),
-                  getattr(self, "_h_dispatch", None)):
-            if h is not None:
-                h.clear()
+        # the ring is process-wide: this tracer reads what it recorded
+        # after this point (export) and after the last mirror (counters)
+        self._ring_base = self._ring_mirrored = _RING.added
+        self._span_s: Dict[str, float] = {}
+        self._span_n: Dict[str, int] = {}
+        # the tracer owns its latency histograms and span counters: a
+        # reset boundary (the engine's reset_counters between timed
+        # passes) zeroes them too, so exported quantiles describe the
+        # LAST pass, not the compile-heavy warmup
+        for fam in (self._h_ttft, self._h_itl, self._h_queue,
+                    self._c_span_s, self._c_span_n):
+            if fam is not None:
+                fam.clear()
 
     # -- hooks the engine calls -------------------------------------------
     @staticmethod
     def now() -> float:
-        return time.perf_counter()
+        return _now()
 
     def on_submit(self, rid: int, t: Optional[float] = None) -> None:
         self._reqs[rid] = _Req(rid, self.now() if t is None else t)
-
-    def annotation(self, kind: str):
-        """Context manager wrapping a dispatch; jax.profiler annotation
-        when enabled, else a no-op."""
-        if self.jax_annotations:
-            import jax
-            return jax.profiler.TraceAnnotation(f"repro.dispatch.{kind}")
-        import contextlib
-        return contextlib.nullcontext()
 
     def on_dispatch(self, kind: str, t0: float, t1: float, *,
                     admitted: Sequence[Tuple[int, int]] = (),
@@ -133,17 +264,15 @@ class Tracer:
                     finished: Sequence[int] = (),
                     queue_depth: int = 0,
                     n_active: int = 0) -> None:
-        """One engine step.  admitted: (slot, rid) pairs newly placed;
-        prefilling: (slot, rid, offset, take) chunks consumed this
-        dispatch; emits: (slot, rid) that produced a token; finished:
-        rids that completed.  Speculative verify dispatches emit UP TO
-        k+1 tokens per slot at once — ``emit_counts`` (aligned with
-        ``emits``) carries the per-slot count so request token totals
-        stay exact; TTFT/ITL remain per-emitting-dispatch timestamps
-        (an accepted run reaches the host as one batch, so the
-        per-round gap IS its inter-token cadence).  Any ``kind`` string
-        flows through to the span name and the dispatch histogram
-        label — the speculative round uses draft/verify/replay."""
+        """One engine dispatch, enqueued from ``t0`` to ``t1``.
+        admitted: (slot, rid) pairs newly placed; prefilling: (slot,
+        rid, offset, take) chunks consumed this dispatch; emits: (slot,
+        rid) that produced a token (``emit_counts``, aligned with
+        ``emits``, carries a speculative verify's per-slot count);
+        finished: rids whose last token this dispatch produced.  Token
+        latencies come from ``on_deliver``, not from here.  Any
+        ``kind`` string flows through to the event name — the
+        speculative round uses draft/verify/replay."""
         i = self._n_dispatch
         self._n_dispatch += 1
         n_emits = (sum(emit_counts) if emit_counts is not None
@@ -154,8 +283,6 @@ class Tracer:
                            "queue_depth": queue_depth,
                            "n_active": n_active,
                            "n_emits": n_emits}))
-        if self._h_dispatch is not None:
-            self._h_dispatch.observe(t1 - t0, kind=kind)
 
         for slot, rid in admitted:
             r = self._reqs.get(rid)
@@ -177,32 +304,41 @@ class Tracer:
 
         for j, (slot, rid) in enumerate(emits):
             n_tok = emit_counts[j] if emit_counts is not None else 1
-            r = self._reqs.get(rid)
             self._emit(_Event(f"decode rid={rid}", t0, t1 - t0, "slots",
                               f"slot {slot}",
                               {"rid": rid, "n_tokens": n_tok}))
-            if r is None:
-                continue
-            r.n_tokens += n_tok
-            if r.t_first_token is None:
-                r.t_first_token = t1
-                if self._h_ttft is not None:
-                    self._h_ttft.observe(t1 - r.t_submit)
-            elif r.t_last_emit is not None and self._h_itl is not None:
-                self._h_itl.observe(t1 - r.t_last_emit)
-            r.t_last_emit = t1
 
         for rid in finished:
             r = self._reqs.get(rid)
-            if r is None:
-                continue
-            r.t_finish = t1
+            if r is not None:
+                r.finished = True
+
+    def on_deliver(self, rid: int, n_tokens: int, t: float) -> None:
+        """``n_tokens`` of request ``rid`` reached the host at ``t``
+        (the end of the engine's token fetch).  Stamps TTFT and the
+        inter-token gaps; the request's span ends at the delivery of
+        its last token."""
+        r = self._reqs.get(rid)
+        if r is None or n_tokens <= 0:
+            return
+        if r.t_first_token is None:
+            r.t_first_token = t
+            if self._h_ttft is not None:
+                self._h_ttft.observe(t - r.t_submit)
+        elif self._h_itl is not None:
+            self._h_itl.observe(t - r.t_last_token)
+        if self._h_itl is not None:
+            for _ in range(n_tokens - 1):
+                self._h_itl.observe(0.0)
+        r.t_last_token = t
+        r.n_tokens += n_tokens
+        if r.finished and r.t_finish is None:
+            r.t_finish = t
             self._emit(_Event(f"request rid={rid}", r.t_submit,
-                              t1 - r.t_submit, "requests", f"rid {rid}",
+                              t - r.t_submit, "requests", f"rid {rid}",
                               {"rid": rid, "n_tokens": r.n_tokens,
-                               "ttft_s": None if r.t_first_token is None
-                               else round(r.t_first_token - r.t_submit,
-                                          6)}))
+                               "ttft_s": round(r.t_first_token
+                                               - r.t_submit, 6)}))
 
     def on_preempt(self, rid: int, slot: int,
                    t: Optional[float] = None) -> None:
@@ -235,6 +371,20 @@ class Tracer:
                            "expert": expert,
                            "false_skip_rate": round(rate, 6)}))
 
+    def mirror_spans(self) -> None:
+        """Add the spans recorded since the last mirror to the per-name
+        totals and set ``repro_engine_span_seconds_total`` /
+        ``repro_engine_spans_total`` from them (the engine's flush)."""
+        for sp in _RING.since(self._ring_mirrored):
+            self._span_s[sp.name] = (self._span_s.get(sp.name, 0.0)
+                                     + sp.t1 - sp.t0)
+            self._span_n[sp.name] = self._span_n.get(sp.name, 0) + 1
+        self._ring_mirrored = _RING.added
+        if self._c_span_s is not None:
+            for name, s in self._span_s.items():
+                self._c_span_s.set(s, span=name)
+                self._c_span_n.set(self._span_n[name], span=name)
+
     def _emit(self, ev: _Event) -> None:
         if len(self._events) >= self.max_events:
             self._dropped += 1
@@ -264,7 +414,8 @@ class Tracer:
                      "n_preemptions": self._n_preempt,
                      "n_restores": self._n_restore,
                      "n_drift_events": self._n_drift,
-                     "events_dropped": self._dropped}
+                     "events_dropped": self._dropped,
+                     "host_spans_dropped": spans_dropped()}
         if self._h_ttft is not None:
             out["ttft"] = self._h_ttft.summary()
             out["itl"] = self._h_itl.summary()
@@ -273,28 +424,35 @@ class Tracer:
 
     def to_chrome_trace(self) -> Dict:
         """Chrome trace event format; ts/dur in microseconds."""
-        pids = sorted({ev.pid for ev in self._events})
+        events = list(self._events)
+        for sp in _RING.since(self._ring_base):
+            events.append(_Event(sp.name, sp.t0, sp.t1 - sp.t0, "host",
+                                 "spans",
+                                 {"id": sp.id, "parent": sp.parent,
+                                  **{k: _jsonable(v)
+                                     for k, v in sp.args.items()}}))
+        pids = sorted({ev.pid for ev in events})
         pid_ids = {p: i + 1 for i, p in enumerate(pids)}
         tid_ids: Dict[Tuple[str, object], int] = {}
-        events: List[Dict] = []
+        out: List[Dict] = []
         for pid, pi in pid_ids.items():
-            events.append({"ph": "M", "name": "process_name", "pid": pi,
-                           "tid": 0, "args": {"name": pid}})
-        for ev in self._events:
+            out.append({"ph": "M", "name": "process_name", "pid": pi,
+                        "tid": 0, "args": {"name": pid}})
+        for ev in events:
             key = (ev.pid, ev.tid)
             if key not in tid_ids:
                 tid_ids[key] = len([k for k in tid_ids
                                     if k[0] == ev.pid]) + 1
-                events.append({"ph": "M", "name": "thread_name",
-                               "pid": pid_ids[ev.pid],
-                               "tid": tid_ids[key],
-                               "args": {"name": str(ev.tid)}})
-            events.append({"ph": "X", "name": ev.name,
-                           "ts": round(ev.ts * 1e6, 3),
-                           "dur": round(max(ev.dur, 0.0) * 1e6, 3),
-                           "pid": pid_ids[ev.pid], "tid": tid_ids[key],
-                           "args": ev.args})
-        return {"traceEvents": events,
+                out.append({"ph": "M", "name": "thread_name",
+                            "pid": pid_ids[ev.pid],
+                            "tid": tid_ids[key],
+                            "args": {"name": str(ev.tid)}})
+            out.append({"ph": "X", "name": ev.name,
+                        "ts": round(ev.ts * 1e6, 3),
+                        "dur": round(max(ev.dur, 0.0) * 1e6, 3),
+                        "pid": pid_ids[ev.pid], "tid": tid_ids[key],
+                        "args": ev.args})
+        return {"traceEvents": out,
                 "displayTimeUnit": "ms",
                 "metadata": {"tool": "repro.obs.tracer"}}
 

@@ -33,7 +33,6 @@ N)`` opts into flushing every N dispatches for incremental delivery.
 """
 from __future__ import annotations
 
-import contextlib
 import time
 from functools import partial
 from typing import Callable, Dict, Iterator, List, Optional
@@ -44,6 +43,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import get_model
+from repro.obs.tracer import span
 from repro.serving import kv_pool
 from repro.serving.policy import Policy, get_policy
 from repro.serving.scheduler import (FREE, Request, RequestRejected,
@@ -150,6 +150,9 @@ class Engine:
             self._mblock = self._mspec.init(n_rows)
         body = partial(self._step_impl, cfg, api, mor_mode,
                        self.temperature, self.top_k, self._mspec)
+        # the jit's name: what its compile spans and its device-trace
+        # module are called (a bare partial is "<unknown>")
+        body.__name__ = "engine_step"
         if layout == "paged-sharded":
             from repro.serving.mesh import make_sharded_step
             self._step = make_sharded_step(body, self.mesh, self.cache)
@@ -263,24 +266,32 @@ class Engine:
             # (emits, nxt (B,)) vanilla dispatches or
             # (emits, tokens (B, K+1), counts (B,)) speculative rounds
             # (counts already host-side — it was the round's one sync)
-            fetched = jax.device_get([e[1] for e in self._tok_log])
-            for entry, toks in zip(self._tok_log, fetched):
-                emits = entry[0]
-                counts = entry[2] if len(entry) > 2 else None
-                toks = np.asarray(toks)
-                for s, rid in emits:
-                    if counts is None:
-                        vals = (int(toks[s]),)
-                    else:
-                        vals = tuple(int(t)
-                                     for t in toks[s, :int(counts[s])])
-                    cb = self._stream_cbs.get(rid)
-                    res = self.results.setdefault(rid, [])
-                    for t in vals:
-                        res.append(t)
-                        if cb is not None:
-                            cb(rid, t)
-            self._tok_log.clear()
+            with span("engine.fetch", dispatches=len(self._tok_log)):
+                fetched = jax.device_get([e[1] for e in self._tok_log])
+            t_home = time.perf_counter()
+            with span("engine.deliver"):
+                delivered: Dict[int, int] = {}
+                for entry, toks in zip(self._tok_log, fetched):
+                    emits = entry[0]
+                    counts = entry[2] if len(entry) > 2 else None
+                    toks = np.asarray(toks)
+                    for s, rid in emits:
+                        if counts is None:
+                            vals = (int(toks[s]),)
+                        else:
+                            vals = tuple(int(t) for t in
+                                         toks[s, :int(counts[s])])
+                        cb = self._stream_cbs.get(rid)
+                        res = self.results.setdefault(rid, [])
+                        for t in vals:
+                            res.append(t)
+                            if cb is not None:
+                                cb(rid, t)
+                        delivered[rid] = delivered.get(rid, 0) + len(vals)
+                self._tok_log.clear()
+                if self._tr is not None:
+                    for rid, n in delivered.items():
+                        self._tr.on_deliver(rid, n, t_home)
         # a flush drains every pending dispatch, so finished requests'
         # callbacks have now delivered their last token — drop them
         # (long-lived engines would otherwise leak one closure per
@@ -487,6 +498,8 @@ class Engine:
         if self.telemetry is not None:
             export_telemetry(reg, self.telemetry, layout=lay,
                              capacities=self.capacities)
+        if self._tr is not None:
+            self._tr.mirror_spans()
 
     def _probe_stat_shapes(self) -> Dict[str, tuple]:
         """Shapes of the step's per-layer MoR stat leaves, via
@@ -717,107 +730,134 @@ class Engine:
     def step(self) -> List[int]:
         """One scheduler iteration: admit (preempting victims when the
         policy or pool pressure demands it), dispatch, ingest.  Returns
-        the rids that finished this step."""
+        the rids that finished this step.
+
+        The iteration is an ``engine.step`` span (``repro.obs.tracer``)
+        whose args record the dispatch: ``kind``, ``rows`` run, each
+        slot's ``pos`` before it and ``n_valid``, and the ``admitted``
+        count.  Its children: ``engine.admit`` (preemption, admission,
+        batch building), ``engine.plan`` (pool hooks, ``plan_writes``,
+        the packed page edits), ``engine.enqueue`` (the compiled step's
+        call and its uploads) and ``engine.feed`` (bookkeeping)."""
+        with span("engine.step") as sp:
+            return self._step_in(sp)
+
+    def _step_in(self, sp: span) -> List[int]:
         t0 = time.perf_counter()
         sched = self.scheduler
-        # policy-driven preemption: when no slot is free, the policy may
-        # evict a running victim so the top waiting request (after its
-        # ordering) gets served now — slots are the scarce resource
-        if self._can_preempt and sched.waiting and \
-                not any(s.state is FREE for s in sched.slots):
-            self.policy.order(sched.waiting)
-            victim = self.policy.select_victim(sched.slots,
-                                               sched.waiting[0])
-            if victim is not None:
-                self._preempt(victim)
-        place = self._place if self.pool is not None else None
-        admitted = sched.admit(place)
-        if self.pool is not None:
-            # admission deferred on pool pressure: spill victims (their
-            # exclusive pages move to host) and retry — bounded, never
-            # touching slots admitted THIS step
-            for _ in range(self.n_slots):
-                if not sched.deferred or not self._can_preempt:
-                    break
-                victim = self.policy.spill_victim(sched.slots,
-                                                  exclude=admitted)
-                if victim is None:
-                    break
-                self._preempt(victim)
-                admitted += sched.admit(place)
-        if admitted and self.pool is None:
-            mask = np.zeros((self.n_slots,), bool)
-            mask[admitted] = True
-            self.cache = self._reset(self.cache, jnp.asarray(mask))
-        kind = sched.peek_kind()
-        if kind is None:
-            if sched.waiting:
-                # nothing runs AND nothing can be admitted: without a
-                # victim to spill this can never make progress
-                raise kv_pool.PoolExhausted(
-                    "no waiting request can be admitted and nothing is "
-                    "running — pool exhausted with no preemption victim")
-            return []
-        # decode-only dispatches upgrade to a speculative round (draft
-        # k tokens cheap, verify them in one target pass) — atomic
-        # inside this step, so preemption above always sees committed
-        # state.  ready() backs off one step after a pool-pressure
-        # abort so the vanilla path's spill machinery can run.
-        if self.spec is not None and kind == "decode" and \
-                self.spec.ready():
-            return self.spec.round(t0, admitted)
-        tokens, n_valid, use_pending, emits, finishing, prefilling = \
-            sched.build_batch(kind)
-        ops = None
-        if self.pool is not None:
-            # pre-dispatch: snapshot recurrent state of slots whose
-            # prompt finishes here (the state at ``offset`` is what the
-            # previous dispatches left in the pool), publish the prefix
-            # of windowed prompts about to wrap their ring (their pages
-            # are still intact NOW — after this dispatch they aren't),
-            # then allocate / copy-on-write every page this dispatch
-            # will touch; the resulting device edits ride into the
-            # fused step as ``ops``.  Pool exhaustion mid-plan spills a
-            # victim and REBUILDS the batch (the victim may have been in
-            # it); the hooks are idempotent and ``plan_writes`` resumes
-            # past blocks already made exclusive, so retrying is safe.
-            for _ in range(self.n_slots + 1):
-                for s, off in finishing:
-                    self.pool.maybe_snapshot(
-                        s, sched.slots[s].req.prompt, off)
-                for s, off, take in prefilling:
-                    self.pool.maybe_publish_prewrap(
-                        s, sched.slots[s].req.prompt, off, take)
-                try:
-                    self.pool.plan_writes(n_valid)
-                    break
-                except kv_pool.PoolExhausted:
-                    victim = (self.policy.spill_victim(sched.slots,
-                                                       exclude=admitted)
-                              if self._can_preempt else None)
-                    if victim is None:
-                        raise
+        with span("engine.admit"):
+            # policy-driven preemption: when no slot is free, the policy
+            # may evict a running victim so the top waiting request
+            # (after its ordering) gets served now — slots are the
+            # scarce resource
+            if self._can_preempt and sched.waiting and \
+                    not any(s.state is FREE for s in sched.slots):
+                self.policy.order(sched.waiting)
+                victim = self.policy.select_victim(sched.slots,
+                                                   sched.waiting[0])
+                if victim is not None:
                     self._preempt(victim)
-                    kind = sched.peek_kind()
-                    if kind is None:        # spilled the whole batch
-                        return []
-                    (tokens, n_valid, use_pending, emits, finishing,
-                     prefilling) = sched.build_batch(kind)
-            else:
-                raise kv_pool.PoolExhausted(
-                    "dispatch cannot fit even after spilling victims")
-            self.cache, ops = self.pool.drain(self.cache)
-        sched.dispatch_kinds[kind] += 1
-        # decode riders in a mixed dispatch: counted at BUILD time (feed()
-        # below flips prefill->decode / frees finished slots)
-        ndec = int(use_pending.sum()) if kind == "mixed" else 0
-        key = jax.random.fold_in(self._base_key, self.counters["dispatches"]) \
-            if self.temperature > 0.0 else self._base_key
-        n_active = (self.pool.active_blocks(n_valid)
-                    if self.pool is not None else None)
-        copy_pads = (self.pool.last_pads
-                     if self.pool is not None and ops is not None else (0, 0))
-        # tracer span bookkeeping (rid lookups must happen BEFORE feed()
+            place = self._place if self.pool is not None else None
+            admitted = sched.admit(place)
+            if self.pool is not None:
+                # admission deferred on pool pressure: spill victims
+                # (their exclusive pages move to host) and retry —
+                # bounded, never touching slots admitted THIS step
+                for _ in range(self.n_slots):
+                    if not sched.deferred or not self._can_preempt:
+                        break
+                    victim = self.policy.spill_victim(sched.slots,
+                                                      exclude=admitted)
+                    if victim is None:
+                        break
+                    self._preempt(victim)
+                    admitted += sched.admit(place)
+            if admitted and self.pool is None:
+                mask = np.zeros((self.n_slots,), bool)
+                mask[admitted] = True
+                self.cache = self._reset(self.cache, jnp.asarray(mask))
+            kind = sched.peek_kind()
+            if kind is None:
+                if sched.waiting:
+                    # nothing runs AND nothing can be admitted: without
+                    # a victim to spill this can never make progress
+                    raise kv_pool.PoolExhausted(
+                        "no waiting request can be admitted and nothing "
+                        "is running — pool exhausted with no preemption "
+                        "victim")
+                return []
+            # decode-only dispatches upgrade to a speculative round
+            # (draft k tokens cheap, verify them in one target pass) —
+            # atomic inside this step, so preemption above always sees
+            # committed state.  ready() backs off one step after a
+            # pool-pressure abort so the vanilla path's spill machinery
+            # can run.
+            spec_round = (self.spec is not None and kind == "decode"
+                          and self.spec.ready())
+            if not spec_round:
+                tokens, n_valid, use_pending, emits, finishing, \
+                    prefilling = sched.build_batch(kind)
+        sp.args["admitted"] = len(admitted)
+        if spec_round:
+            sp.args["kind"] = "spec"
+            return self.spec.round(t0, admitted)
+        ops = None
+        with span("engine.plan"):
+            if self.pool is not None:
+                # pre-dispatch: snapshot recurrent state of slots whose
+                # prompt finishes here (the state at ``offset`` is what
+                # the previous dispatches left in the pool), publish the
+                # prefix of windowed prompts about to wrap their ring
+                # (their pages are still intact NOW — after this
+                # dispatch they aren't), then allocate / copy-on-write
+                # every page this dispatch will touch; the resulting
+                # device edits ride into the fused step as ``ops``.
+                # Pool exhaustion mid-plan spills a victim and REBUILDS
+                # the batch (the victim may have been in it); the hooks
+                # are idempotent and ``plan_writes`` resumes past blocks
+                # already made exclusive, so retrying is safe.
+                for _ in range(self.n_slots + 1):
+                    for s, off in finishing:
+                        self.pool.maybe_snapshot(
+                            s, sched.slots[s].req.prompt, off)
+                    for s, off, take in prefilling:
+                        self.pool.maybe_publish_prewrap(
+                            s, sched.slots[s].req.prompt, off, take)
+                    try:
+                        self.pool.plan_writes(n_valid)
+                        break
+                    except kv_pool.PoolExhausted:
+                        victim = (self.policy.spill_victim(
+                            sched.slots, exclude=admitted)
+                            if self._can_preempt else None)
+                        if victim is None:
+                            raise
+                        self._preempt(victim)
+                        kind = sched.peek_kind()
+                        if kind is None:    # spilled the whole batch
+                            return []
+                        (tokens, n_valid, use_pending, emits, finishing,
+                         prefilling) = sched.build_batch(kind)
+                else:
+                    raise kv_pool.PoolExhausted(
+                        "dispatch cannot fit even after spilling victims")
+                self.cache, ops = self.pool.drain(self.cache)
+            sched.dispatch_kinds[kind] += 1
+            # decode riders in a mixed dispatch: counted at BUILD time
+            # (feed() below flips prefill->decode / frees finished slots)
+            ndec = int(use_pending.sum()) if kind == "mixed" else 0
+            key = jax.random.fold_in(self._base_key,
+                                     self.counters["dispatches"]) \
+                if self.temperature > 0.0 else self._base_key
+            n_active = (self.pool.active_blocks(n_valid)
+                        if self.pool is not None else None)
+            copy_pads = (self.pool.last_pads
+                         if self.pool is not None and ops is not None
+                         else (0, 0))
+        sp.args.update(kind=kind, rows=int(tokens.size), n_valid=n_valid)
+        if self.pool is not None:
+            sp.args["pos"] = self.pool.pos.copy()
+        # tracer bookkeeping (rid lookups must happen BEFORE feed()
         # below frees finished slots); None when tracing is off
         if self._tr is not None:
             slots = self.scheduler.slots
@@ -825,9 +865,6 @@ class Engine:
             tr_admitted = [(s, slots[s].req.rid) for s in admitted]
             tr_prefilling = [(s, slots[s].req.rid, off, take)
                              for s, off, take in prefilling]
-            ann = self._tr.annotation(kind)
-        else:
-            ann = contextlib.nullcontext()
         # shadow-oracle sampling: every Nth vanilla dispatch is scored.
         # Tiled plans swap the scored twin INTO the primary dispatch
         # (bitwise-identical activations, no extra forward); kernel /
@@ -836,56 +873,57 @@ class Engine:
         # twin reads — and keep the primary's tokens authoritative.
         sampled = (self._shadow_every is not None and
                    self.counters["dispatches"] % self._shadow_every == 0)
-        if sampled and self._shadow_step is not None:
-            self._mblock = self._shadow_step(
-                self.params, self._shadow_mor, self.cache,
-                jnp.asarray(tokens), jnp.asarray(n_valid),
-                jnp.asarray(use_pending), self._pending, ops,
-                self._mblock, n_active, copy_pads)
-        mor_step = (self._shadow_mor
-                    if sampled and self._shadow_step is None else self.mor)
-        with ann:
+        with span("engine.enqueue"):
+            if sampled and self._shadow_step is not None:
+                self._mblock = self._shadow_step(
+                    self.params, self._shadow_mor, self.cache,
+                    jnp.asarray(tokens), jnp.asarray(n_valid),
+                    jnp.asarray(use_pending), self._pending, ops,
+                    self._mblock, n_active, copy_pads)
+            mor_step = (self._shadow_mor if sampled and
+                        self._shadow_step is None else self.mor)
             nxt, self._pending, self.cache, aux, self._mblock = self._step(
                 self.params, mor_step, self.cache, jnp.asarray(tokens),
                 jnp.asarray(n_valid), jnp.asarray(use_pending),
                 self._pending, key, ops, self._mblock, n_active, copy_pads)
-        if self.pool is not None:
-            self.pool.advance(n_valid)
-        if emits:
-            self._tok_log.append((emits, nxt))
-        if self.telemetry is not None and aux:
-            # buffer the (device) stat arrays; host conversion happens
-            # lazily in _flush_telemetry so the dispatch loop never syncs
-            # on telemetry
-            self._aux_log.append(aux)
-        finished, entering = self.scheduler.feed(n_valid)
-        for _, req in finished:
-            if req.rid in self._stream_cbs:
-                self._stream_done.add(req.rid)
-        if self.pool is not None:
-            # publish AFTER the dispatch that wrote the prompt's last
-            # pages; release AFTER publish so a request finishing in the
-            # same step still shares its pages
-            for s, req in entering:
-                self.pool.publish(s, req.prompt)
-            for s, _ in finished:
-                self.pool.release(s)
-        self.counters["dispatches"] += 1
-        nv_total = int(n_valid.sum())
-        if kind == "decode":
-            self.counters["decode_tokens"] += nv_total
-        else:
-            # decode slots riding in a mixed dispatch contribute 1 each
-            self.counters["decode_tokens"] += ndec
-            self.counters["prefill_tokens"] += nv_total - ndec
-        self.counters["wall_s"] += time.perf_counter() - t0
-        if self._tr is not None:
-            self._tr.on_dispatch(
-                kind, tr_t0, self._tr.now(), admitted=tr_admitted,
-                prefilling=tr_prefilling, emits=emits,
-                finished=[req.rid for _, req in finished],
-                queue_depth=len(self.scheduler.waiting),
-                n_active=int(np.count_nonzero(n_valid)))
+        with span("engine.feed"):
+            if self.pool is not None:
+                self.pool.advance(n_valid)
+            if emits:
+                self._tok_log.append((emits, nxt))
+            if self.telemetry is not None and aux:
+                # buffer the (device) stat arrays; host conversion
+                # happens lazily in _flush_telemetry so the dispatch
+                # loop never syncs on telemetry
+                self._aux_log.append(aux)
+            finished, entering = self.scheduler.feed(n_valid)
+            for _, req in finished:
+                if req.rid in self._stream_cbs:
+                    self._stream_done.add(req.rid)
+            if self.pool is not None:
+                # publish AFTER the dispatch that wrote the prompt's
+                # last pages; release AFTER publish so a request
+                # finishing in the same step still shares its pages
+                for s, req in entering:
+                    self.pool.publish(s, req.prompt)
+                for s, _ in finished:
+                    self.pool.release(s)
+            self.counters["dispatches"] += 1
+            nv_total = int(n_valid.sum())
+            if kind == "decode":
+                self.counters["decode_tokens"] += nv_total
+            else:
+                # decode slots riding in a mixed dispatch contribute 1
+                self.counters["decode_tokens"] += ndec
+                self.counters["prefill_tokens"] += nv_total - ndec
+            self.counters["wall_s"] += time.perf_counter() - t0
+            if self._tr is not None:
+                self._tr.on_dispatch(
+                    kind, tr_t0, self._tr.now(), admitted=tr_admitted,
+                    prefilling=tr_prefilling, emits=emits,
+                    finished=[req.rid for _, req in finished],
+                    queue_depth=len(self.scheduler.waiting),
+                    n_active=int(np.count_nonzero(n_valid)))
         return [req.rid for _, req in finished]
 
     def reset_counters(self) -> None:
@@ -922,10 +960,17 @@ class Engine:
         """Flush boundary without draining the queue: deliver the token
         log to host (+ stream callbacks) and push telemetry/obs mirrors.
         Open-loop drivers stepping the engine themselves call this once
-        the arrival stream ends (``run`` does it implicitly)."""
-        self._flush_tokens()
-        self._flush_telemetry()
-        self._flush_obs()
+        the arrival stream ends (``run`` does it implicitly).  An
+        ``engine.drain`` span, with children ``engine.fetch`` (the one
+        ``jax.device_get`` of the token log: the host waiting on the
+        device), ``engine.deliver`` (callbacks, ``results``),
+        ``engine.telemetry`` and ``engine.obs``."""
+        with span("engine.drain"):
+            self._flush_tokens()
+            with span("engine.telemetry"):
+                self._flush_telemetry()
+            with span("engine.obs"):
+                self._flush_obs()
 
     def run(self, requests=None,
             stream_interval: int = 0) -> Dict[int, List[int]]:
@@ -945,9 +990,7 @@ class Engine:
             if stream_interval > 0 and \
                     self.counters["dispatches"] % stream_interval == 0:
                 self._flush_tokens()
-        self._flush_tokens()
-        self._flush_telemetry()
-        self._flush_obs()
+        self.drain()
         if requests:
             return {rid: toks for rid, toks in self.results.items()
                     if rid >= first_rid}
@@ -973,9 +1016,7 @@ class Engine:
                 while served < len(got):
                     yield got[served]
                     served += 1
-            self._flush_tokens()
-            self._flush_telemetry()
-            self._flush_obs()
+            self.drain()
             while served < len(got):
                 yield got[served]
                 served += 1

@@ -14,10 +14,11 @@ A vs policy B comparisons (and CI reruns) see the SAME offered load.
 ``run_open_loop`` replays a trace against a live engine in real time:
 arrivals whose time has come are submitted (rejections recorded, never
 fatal — that is what ``RequestRejected`` is for), the engine steps
-whenever it has work, and the driver sleeps only when idle ahead of the
-next arrival.  Per-request latencies come out of the engine's tracer
-(``Tracer.request_spans``), which shares the ``perf_counter`` timebase
-with the arrival clock.
+whenever it has work and drains after each step (its tokens reach the
+host, as a streaming server's would), and the generator sleeps only when
+idle ahead of the next arrival.  Per-request latencies come out of the
+engine's tracer (``Tracer.request_spans``, stamped on delivery), which
+shares the ``perf_counter`` timebase with the arrival clock.
 """
 from __future__ import annotations
 
@@ -94,8 +95,9 @@ def run_open_loop(engine, arrivals: List[Arrival], *,
                   time_scale: float = 1.0,
                   idle_sleep_cap: float = 0.002) -> OpenLoopResult:
     """Replay ``arrivals`` against ``engine`` in real time: submit every
-    arrival whose (scaled) time has passed, step the engine whenever it
-    has work, sleep only when idle before the next arrival, then drain.
+    arrival whose (scaled) time has passed, step and drain the engine
+    whenever it has work (each step's tokens are delivered before the
+    next), sleep only when idle before the next arrival.
     Rejections (oversize injections, etc.) are recorded and the run
     continues — a load generator that dies on one bad request measures
     nothing."""
@@ -115,11 +117,11 @@ def run_open_loop(engine, arrivals: List[Arrival], *,
             i += 1
         if engine.scheduler.has_work:
             engine.step()
+            engine.drain()
         elif i < n:
             wait = arrivals[i].t * time_scale - (time.perf_counter() - t0)
             if wait > 0:
                 time.sleep(min(wait, idle_sleep_cap))
-    engine.drain()
     res.wall_s = time.perf_counter() - t0
     return res
 

@@ -42,7 +42,6 @@ block (drained once per flush).
 """
 from __future__ import annotations
 
-import contextlib
 import time
 from functools import partial
 from typing import Dict, List, Optional, Tuple
@@ -51,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.tracer import span
 from repro.serving import kv_pool
 
 __all__ = ["sample_step", "accept_greedy", "accept_sampled",
@@ -399,9 +399,13 @@ class SpecDecoder:
     def round(self, t0: float, admitted: List[int]) -> List[int]:
         """One speculative round: fork -> k draft dispatches -> verify
         dispatch -> commit/rollback (+ optional state replay) -> feed.
-        Exactly one host sync (the per-slot emit counts).  Falls back
-        to one vanilla ``Engine.step`` when the pool cannot host the
-        round."""
+        Exactly one host sync (the per-slot emit counts, an
+        ``engine.fetch`` span).  Falls back to one vanilla
+        ``Engine.step`` when the pool cannot host the round.  Runs
+        inside the caller's ``engine.step`` span; each dispatch has its
+        ``engine.plan``, ``engine.enqueue`` (args ``kind``: draft /
+        verify / replay) and ``engine.feed`` spans, as a vanilla step
+        does."""
         eng = self.eng
         sched, pool = eng.scheduler, eng.pool
         K, B = self.k, eng.n_slots
@@ -415,9 +419,6 @@ class SpecDecoder:
             self._abort(forks)
             return eng.step()
 
-        ann = (eng._tr.annotation if eng._tr is not None
-               else lambda _k: contextlib.nullcontext())
-
         # -- draft loop: kmax host iterations of ONE compiled step
         # (n_valid masks slots past their per-slot length) ------------
         pending = eng._pending          # round-local; committed pending
@@ -425,75 +426,80 @@ class SpecDecoder:
         draft_probs: List = []          # rollback / preemption safety
         try:
             for i in range(kmax):
-                nv = (k_s > i).astype(np.int32)
-                pool.plan_writes(nv)
-                eng.cache, ops = pool.drain(eng.cache)
-                n_active = pool.active_blocks(nv)
-                copy_pads = (pool.last_pads if ops is not None
-                             else (0, 0))
-                key = (jax.random.fold_in(eng._base_key,
-                                          eng.counters["dispatches"])
-                       if self.draft_temperature > 0.0
-                       else eng._base_key)
+                with span("engine.plan"):
+                    nv = (k_s > i).astype(np.int32)
+                    pool.plan_writes(nv)
+                    eng.cache, ops = pool.drain(eng.cache)
+                    n_active = pool.active_blocks(nv)
+                    copy_pads = (pool.last_pads if ops is not None
+                                 else (0, 0))
+                    key = (jax.random.fold_in(eng._base_key,
+                                              eng.counters["dispatches"])
+                           if self.draft_temperature > 0.0
+                           else eng._base_key)
                 tr_t0 = eng._tr.now() if eng._tr is not None else 0.0
-                with ann("draft"):
+                with span("engine.enqueue", kind="draft"):
                     nxt, probs, pending, eng.cache, eng._mblock = \
                         self._draft(
                             eng.params, self.mor_draft, eng.cache,
                             jnp.asarray(nv), pending, key, ops,
                             eng._mblock, n_active, copy_pads)
-                pool.advance(nv)
-                draft_toks.append(nxt)
-                draft_probs.append(probs)
-                eng.counters["dispatches"] += 1
-                sched.dispatch_kinds["draft"] += 1
-                self.counters["tokens_drafted"] += int(nv.sum())
-                if eng._tr is not None:
-                    eng._tr.on_dispatch(
-                        "draft", tr_t0, eng._tr.now(),
-                        queue_depth=len(sched.waiting),
-                        n_active=int(nv.sum()))
+                with span("engine.feed"):
+                    pool.advance(nv)
+                    draft_toks.append(nxt)
+                    draft_probs.append(probs)
+                    eng.counters["dispatches"] += 1
+                    sched.dispatch_kinds["draft"] += 1
+                    self.counters["tokens_drafted"] += int(nv.sum())
+                    if eng._tr is not None:
+                        eng._tr.on_dispatch(
+                            "draft", tr_t0, eng._tr.now(),
+                            queue_depth=len(sched.waiting),
+                            n_active=int(nv.sum()))
 
             # -- verify: reset to the fork point, score k+1 positions
             # under the target plans in one prefill-shaped pass -------
-            for f in forks:
-                pool.spec_set_pos(f.slot, f.pos)
-                pool.spec_restore_state(f)
-            nvv = np.where(active, k_s + 1, 0).astype(np.int32)
-            pool.plan_writes(nvv)
+            with span("engine.plan"):
+                for f in forks:
+                    pool.spec_set_pos(f.slot, f.pos)
+                    pool.spec_restore_state(f)
+                nvv = np.where(active, k_s + 1, 0).astype(np.int32)
+                pool.plan_writes(nvv)
         except kv_pool.PoolExhausted:
             self._abort(forks)
             return eng.step()
-        eng.cache, ops = pool.drain(eng.cache)
-        n_active = pool.active_blocks(nvv)
-        copy_pads = pool.last_pads if ops is not None else (0, 0)
-        if kmax:
-            dstack = jnp.stack(draft_toks, axis=1)
-            if kmax < K:
-                dstack = jnp.concatenate(
-                    [dstack, jnp.zeros((B, K - kmax), jnp.int32)],
-                    axis=1)
-        else:
-            dstack = jnp.zeros((B, K), jnp.int32)
-        tokens = jnp.concatenate(
-            [jnp.zeros((B, 1), jnp.int32), dstack], axis=1)
-        qstack = None
-        if eng.temperature > 0.0 and self.draft_temperature > 0.0:
-            V = draft_probs[0].shape[-1] if kmax else eng.cfg.vocab_size
+        with span("engine.plan"):
+            eng.cache, ops = pool.drain(eng.cache)
+            n_active = pool.active_blocks(nvv)
+            copy_pads = pool.last_pads if ops is not None else (0, 0)
             if kmax:
-                qstack = jnp.stack(draft_probs, axis=1)
+                dstack = jnp.stack(draft_toks, axis=1)
                 if kmax < K:
-                    qstack = jnp.concatenate(
-                        [qstack,
-                         jnp.full((B, K - kmax, V), 1.0, jnp.float32)],
+                    dstack = jnp.concatenate(
+                        [dstack, jnp.zeros((B, K - kmax), jnp.int32)],
                         axis=1)
             else:
-                qstack = jnp.full((B, K, V), 1.0, jnp.float32)
-        key = (jax.random.fold_in(eng._base_key,
-                                  eng.counters["dispatches"])
-               if eng.temperature > 0.0 else eng._base_key)
+                dstack = jnp.zeros((B, K), jnp.int32)
+            tokens = jnp.concatenate(
+                [jnp.zeros((B, 1), jnp.int32), dstack], axis=1)
+            qstack = None
+            if eng.temperature > 0.0 and self.draft_temperature > 0.0:
+                V = (draft_probs[0].shape[-1] if kmax
+                     else eng.cfg.vocab_size)
+                if kmax:
+                    qstack = jnp.stack(draft_probs, axis=1)
+                    if kmax < K:
+                        qstack = jnp.concatenate(
+                            [qstack,
+                             jnp.full((B, K - kmax, V), 1.0, jnp.float32)],
+                            axis=1)
+                else:
+                    qstack = jnp.full((B, K, V), 1.0, jnp.float32)
+            key = (jax.random.fold_in(eng._base_key,
+                                      eng.counters["dispatches"])
+                   if eng.temperature > 0.0 else eng._base_key)
         tr_t0 = eng._tr.now() if eng._tr is not None else 0.0
-        with ann("verify"):
+        with span("engine.enqueue", kind="verify"):
             emit_toks, n_emit_dev, new_pending, eng.cache, aux, \
                 eng._mblock = self._verify(
                     eng.params, eng.mor, eng.cache, tokens,
@@ -507,7 +513,8 @@ class SpecDecoder:
 
         # the round's single host sync: per-slot emit counts drive the
         # host-side commit/rollback and the scheduler feed
-        n_emit = np.asarray(jax.device_get(n_emit_dev), np.int64)
+        with span("engine.fetch", what="emit_counts"):
+            n_emit = np.asarray(jax.device_get(n_emit_dev), np.int64)
 
         # -- commit / rollback ----------------------------------------
         replays: List[Tuple] = []
@@ -526,19 +533,20 @@ class SpecDecoder:
             # verified token (verify over-advanced it by the rejected
             # tail); attention-only families need none — their rollback
             # is pure position truncation
-            nvr = np.zeros((B,), np.int32)
-            for f, m in replays:
-                pool.spec_set_pos(f.slot, f.pos)
-                pool.spec_restore_state(f)
-                nvr[f.slot] = m
-            # every page involved is already exclusively owned (written
-            # this round), so this plan cannot raise
-            pool.plan_writes(nvr)
-            eng.cache, ops = pool.drain(eng.cache)
-            n_active = pool.active_blocks(nvr)
-            copy_pads = pool.last_pads if ops is not None else (0, 0)
+            with span("engine.plan"):
+                nvr = np.zeros((B,), np.int32)
+                for f, m in replays:
+                    pool.spec_set_pos(f.slot, f.pos)
+                    pool.spec_restore_state(f)
+                    nvr[f.slot] = m
+                # every page involved is already exclusively owned
+                # (written this round), so this plan cannot raise
+                pool.plan_writes(nvr)
+                eng.cache, ops = pool.drain(eng.cache)
+                n_active = pool.active_blocks(nvr)
+                copy_pads = pool.last_pads if ops is not None else (0, 0)
             tr_t0r = eng._tr.now() if eng._tr is not None else 0.0
-            with ann("replay"):
+            with span("engine.enqueue", kind="replay"):
                 eng.cache, eng._mblock = self._replay(
                     eng.params, eng.mor, eng.cache, tokens,
                     jnp.asarray(nvr), eng._pending, ops, eng._mblock,
@@ -556,31 +564,32 @@ class SpecDecoder:
                     n_active=len(replays))
 
         # -- feed / emit ------------------------------------------------
-        eng._pending = new_pending
-        slots = sched.slots
-        emits = [(int(s), slots[s].req.rid)
-                 for s in np.nonzero(active)[0]]
-        if eng._tr is not None:
-            tr_admitted = [(s, slots[s].req.rid) for s in admitted]
-            tr_counts = [int(n_emit[s]) for s, _ in emits]
-        eng._tok_log.append((emits, emit_toks, n_emit))
-        finished = sched.feed_counts(n_emit)
-        for _, req in finished:
-            if req.rid in eng._stream_cbs:
-                eng._stream_done.add(req.rid)
-        for s, _ in finished:
-            pool.release(s)
-        emitted = int(n_emit.sum())
-        accepted = emitted - len(emits)
-        self.counters["rounds"] += 1
-        self.counters["tokens_accepted"] += accepted
-        eng.counters["decode_tokens"] += emitted
-        eng.counters["wall_s"] += time.perf_counter() - t0
-        if eng._tr is not None:
-            eng._tr.on_dispatch(
-                "verify", tr_t0, eng._tr.now(), admitted=tr_admitted,
-                emits=emits, emit_counts=tr_counts,
-                finished=[req.rid for _, req in finished],
-                queue_depth=len(sched.waiting),
-                n_active=int(np.count_nonzero(nvv)))
+        with span("engine.feed"):
+            eng._pending = new_pending
+            slots = sched.slots
+            emits = [(int(s), slots[s].req.rid)
+                     for s in np.nonzero(active)[0]]
+            if eng._tr is not None:
+                tr_admitted = [(s, slots[s].req.rid) for s in admitted]
+                tr_counts = [int(n_emit[s]) for s, _ in emits]
+            eng._tok_log.append((emits, emit_toks, n_emit))
+            finished = sched.feed_counts(n_emit)
+            for _, req in finished:
+                if req.rid in eng._stream_cbs:
+                    eng._stream_done.add(req.rid)
+            for s, _ in finished:
+                pool.release(s)
+            emitted = int(n_emit.sum())
+            accepted = emitted - len(emits)
+            self.counters["rounds"] += 1
+            self.counters["tokens_accepted"] += accepted
+            eng.counters["decode_tokens"] += emitted
+            eng.counters["wall_s"] += time.perf_counter() - t0
+            if eng._tr is not None:
+                eng._tr.on_dispatch(
+                    "verify", tr_t0, eng._tr.now(), admitted=tr_admitted,
+                    emits=emits, emit_counts=tr_counts,
+                    finished=[req.rid for _, req in finished],
+                    queue_depth=len(sched.waiting),
+                    n_active=int(np.count_nonzero(nvv)))
         return [req.rid for _, req in finished]

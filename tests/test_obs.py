@@ -17,6 +17,7 @@ from repro.models import get_model
 from repro.obs import (DeviceMetricsSpec, MetricsRegistry, Observability,
                        Tracer, validate_chrome_trace)
 from repro.obs.device import SCALE
+from repro.obs.tracer import host_spans, span
 from repro.serving import Engine
 
 
@@ -138,7 +139,10 @@ except ModuleNotFoundError:
 
 def _drive_tracer(tr):
     """One scripted request: submit -> mixed dispatch (admit + full
-    prefill, emits first token) -> two decode dispatches -> finish."""
+    prefill, emits first token) -> two decode dispatches -> a last
+    dispatch that finishes it.  Each dispatch's tokens reach the host
+    2 ms after it was enqueued; the last delivery carries two tokens
+    (a speculative round's burst)."""
     t = [100.0]
 
     def tick(dt):
@@ -150,30 +154,38 @@ def _drive_tracer(tr):
     tr.on_dispatch("mixed", t0, tick(0.020), admitted=[(0, 7)],
                    prefilling=[(0, 7, 0, 8)], emits=[(0, 7)],
                    finished=[], queue_depth=0, n_active=1)
+    tr.on_deliver(7, 1, tick(0.002))
     for _ in range(2):
         t0 = tick(0.001)
         tr.on_dispatch("decode", t0, tick(0.005), admitted=[],
                        prefilling=[], emits=[(0, 7)], finished=[],
                        queue_depth=0, n_active=1)
+        tr.on_deliver(7, 1, tick(0.002))
     t0 = tick(0.001)
     tr.on_dispatch("decode", t0, tick(0.005), admitted=[], prefilling=[],
                    emits=[(0, 7)], finished=[7], queue_depth=0,
                    n_active=1)
+    tr.on_deliver(7, 2, tick(0.002))
 
 
 def test_tracer_span_ordering_and_latencies():
     reg = MetricsRegistry()
     tr = Tracer(registry=reg)
-    _drive_tracer(tr)
+    with span("engine.step", kind="decode"):
+        _drive_tracer(tr)
     s = tr.summary()
     assert s["n_requests"] == 1 and s["n_dispatches"] == 4
-    # TTFT = submit -> end of the first emitting dispatch = 10 + 20 ms
+    # TTFT = submit -> first delivery = 10 + 20 + 2 ms, not the end of
+    # the dispatch that emitted it (its enqueue)
     assert s["ttft"]["count"] == 1
-    assert s["ttft"]["max"] == pytest.approx(0.030, abs=1e-6)
-    # ITL between the three emitting-dispatch ends: 6ms each
-    assert s["itl"]["count"] == 3
-    assert s["itl"]["max"] == pytest.approx(0.006, abs=1e-6)
+    assert s["ttft"]["max"] == pytest.approx(0.032, abs=1e-6)
+    # ITL between deliveries 8ms apart, and 0 between the two tokens
+    # delivered together
+    assert s["itl"]["count"] == 4
+    assert s["itl"]["max"] == pytest.approx(0.008, abs=1e-6)
+    assert s["itl"]["min"] == 0.0
     assert s["queue_wait"]["max"] == pytest.approx(0.010, abs=1e-6)
+    assert tr.request_spans()[7]["n_tokens"] == 5
     obj = tr.to_chrome_trace()
     assert validate_chrome_trace(obj) == []
     pid_name = {e["pid"]: e["args"]["name"] for e in obj["traceEvents"]
@@ -184,7 +196,7 @@ def test_tracer_span_ordering_and_latencies():
         by_name.setdefault(e["name"].split()[0].split("/")[0],
                            []).append(e)
     # spans nest: queued precedes prefill, prefill precedes decode,
-    # the request span covers submit -> finish
+    # the request span covers submit -> its last token's delivery
     q = by_name["queued"][0]
     pf = by_name["prefill"][0]
     dec = by_name["decode"]
@@ -193,10 +205,15 @@ def test_tracer_span_ordering_and_latencies():
     assert all(pf["ts"] + pf["dur"] <= d["ts"] + 1
                for d in dec[1:])           # decode spans after prefill
     assert req["ts"] <= q["ts"]
-    assert req["dur"] >= (pf["ts"] + pf["dur"]) - req["ts"] - 1
-    # dispatch spans ride the engine pid, slot spans the slots pid
+    assert req["dur"] == pytest.approx(56e3, abs=1)
+    assert req["args"]["ttft_s"] == pytest.approx(0.032, abs=1e-6)
+    # dispatch spans ride the engine pid, slot spans the slots pid, the
+    # host span ring's spans (args included) the host pid
     assert {pid_name[e["pid"]] for e in by_name["dispatch"]} == {"engine"}
     assert pid_name[pf["pid"]] == "slots"
+    (host,) = by_name["engine.step"]
+    assert pid_name[host["pid"]] == "host"
+    assert host["args"]["kind"] == "decode"
 
 
 def test_tracer_reset_clears_histograms():
@@ -321,6 +338,112 @@ def test_engine_report_obs_sections():
     reg = obs.registry
     assert reg.get("repro_engine_dispatches_total") \
               .get(layout="paged") == rep["dispatches"]
+    # the span ring is mirrored into the span counters at the flush
+    assert reg.get("repro_engine_spans_total") \
+              .get(span="engine.step") == rep["dispatches"]
+    assert reg.get("repro_engine_span_seconds_total") \
+              .get(span="engine.enqueue") > 0
+
+
+# -- host spans inside the engine ----------------------------------------
+
+ENGINE_STEP_CHILDREN = ["engine.admit", "engine.plan", "engine.enqueue",
+                        "engine.feed"]
+
+
+@pytest.fixture(scope="module")
+def engine_ticks(tmp_path_factory):
+    """A reduced paged engine driven for a few step/drain ticks (as the
+    chip harness drives it) under a CPU profiler trace: the span ring's
+    spans of those ticks, what the pool's ``advance`` saw per dispatch,
+    and the trace's xplane."""
+    eng, reqs = _mini_engine()
+    for p, g in reqs:
+        eng.submit(p, g)
+    booked = []
+    advance = eng.pool.advance
+
+    def booked_advance(n_valid):
+        booked.append((eng.pool.pos.copy(), np.asarray(n_valid).copy()))
+        return advance(n_valid)
+
+    eng.pool.advance = booked_advance
+    trace_dir = tmp_path_factory.mktemp("trace")
+    host_spans().clear()
+    n_ticks = 4
+    with jax.profiler.trace(str(trace_dir)):
+        for _ in range(n_ticks):
+            eng.step()
+            eng.drain()
+    (xplane,) = trace_dir.glob("plugins/profile/*/*.xplane.pb")
+    return list(host_spans()), booked, n_ticks, eng, xplane
+
+
+def _inside(child, parent):
+    return parent.t0 <= child.t0 and child.t1 <= parent.t1
+
+
+def test_engine_span_tree(engine_ticks):
+    spans, booked, n_ticks, eng, _ = engine_ticks
+    by_id = {s.id: s for s in spans}
+    roots = sorted((s for s in spans if s.parent == 0), key=lambda s: s.t0)
+    assert [s.name for s in roots] == \
+        ["engine.step", "engine.drain"] * n_ticks
+    kids = {}
+    for s in spans:
+        if s.parent:
+            assert _inside(s, by_id[s.parent]), s
+            kids.setdefault(s.parent, []).append(s)
+    steps = [s for s in roots if s.name == "engine.step"]
+    for sp, (pos, n_valid) in zip(steps, booked, strict=True):
+        names = [c.name for c in sorted(kids[sp.id], key=lambda c: c.t0)]
+        assert names == ENGINE_STEP_CHILDREN
+        # the step's args carry what the pool's advance was handed
+        np.testing.assert_array_equal(sp.args["n_valid"], n_valid)
+        np.testing.assert_array_equal(sp.args["pos"], pos)
+        chunk = eng.chunk if sp.args["kind"] == "mixed" else 1
+        assert sp.args["rows"] == eng.n_slots * chunk
+        assert sp.args["admitted"] == (2 if sp is steps[0] else 0)
+    fetches = [s for s in spans if s.name == "engine.fetch"]
+    assert fetches and all(by_id[f.parent].name == "engine.drain"
+                           for f in fetches)
+    for dr in (s for s in roots if s.name == "engine.drain"):
+        names = [c.name for c in sorted(kids[dr.id], key=lambda c: c.t0)]
+        assert names[-2:] == ["engine.telemetry", "engine.obs"]
+        assert names[:-2] in ([], ["engine.fetch", "engine.deliver"])
+
+
+def test_engine_spans_in_device_trace(engine_ticks):
+    """The same spans are profiler annotations: the xplane's host plane
+    holds them, nested as in the ring."""
+    from jax.profiler import ProfileData
+    _, _, n_ticks, _, xplane = engine_ticks
+    evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+           for p in ProfileData.from_file(str(xplane)).planes
+           if not p.name.startswith("/device:")
+           for ln in p.lines for e in ln.events
+           if e.name.startswith("engine.")]
+    by_name = {}
+    for ev in evs:
+        by_name.setdefault(ev[0], []).append(ev)
+    assert len(by_name["engine.step"]) == len(by_name["engine.drain"]) \
+        == n_ticks
+
+    def within(name, parent):
+        return all(any(a0 <= c0 and c1 <= a1
+                       for _, a0, a1 in by_name[parent])
+                   for _, c0, c1 in by_name[name])
+    assert all(within(c, "engine.step") for c in ENGINE_STEP_CHILDREN)
+    assert within("engine.fetch", "engine.drain")
+
+
+def test_compile_span_names_the_jitted_step(engine_ticks):
+    spans = engine_ticks[0]
+    by_id = {s.id: s for s in spans}
+    comp = [s for s in spans if s.name == "jax.compile"
+            and "engine_step" in s.args["fun"]]
+    assert comp
+    assert all(by_id[s.parent].name == "engine.enqueue" for s in comp)
 
 
 # -- kernel trace scopes ---------------------------------------------------
