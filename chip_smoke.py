@@ -148,6 +148,9 @@ def kernel_phase(cfg, smoke: Smoke) -> dict:
     h = jnp.asarray(rng.standard_normal((M, N)), dt)
     w_down = jnp.asarray(rng.standard_normal((N, K)) / np.sqrt(N), dt)
     kmask = jnp.asarray(rng.random((M // tm, N // 128)) < 0.5)
+    # the kernel's contract: dead h tiles are exact zeros (gather_matmul
+    # makes them so in the FFN); it skips only blocks with no live tile
+    h = jnp.where(jnp.repeat(jnp.repeat(kmask, tm, 0), 128, 1), h, 0)
     got = ops.masked_matmul_kdim(h, w_down, kmask, tile_m=tm)
     with jax.default_matmul_precision("highest"):
         want = ref.masked_matmul_kdim_ref(h, w_down, kmask, tm, 128)

@@ -76,6 +76,7 @@ def _dense_stats(shadow: bool = False) -> Dict[str, jax.Array]:
     out = {"frac_computed": jnp.ones((), jnp.float32),
            "frac_tiles_live": jnp.ones((), jnp.float32),
            "frac_tiles_computed": jnp.ones((), jnp.float32),
+           "frac_blocks_issued": jnp.ones((), jnp.float32),
            "frac_mispredicted_zero": z,
            # integer tile counters (obs device-metrics lanes); dense
            # has no tile grid, so both are zero — the keyset still has
@@ -106,9 +107,14 @@ class MoRPrediction:
     ``tiles`` when capacity covers every live tile).
     ``kernel_counts``: (n_live, n_computed) tile counters reported by
     ``gather_matmul`` itself in kernel mode — the authoritative source
-    for the serving telemetry's realised-skip stats on that path."""
+    for the serving telemetry's realised-skip stats on that path.
+    ``block_counts``: int32 [weight blocks issued, weight blocks], summed
+    over the kernel-mode matmuls that consumed this prediction (the
+    kernels block many tiles; a block is skipped only when all are
+    dead)."""
 
-    __slots__ = ("computed", "tiles", "kept", "kernel_counts")
+    __slots__ = ("computed", "tiles", "kept", "kernel_counts",
+                 "block_counts")
 
     def __init__(self, computed: Optional[jax.Array], tiles: jax.Array,
                  kept: Optional[jax.Array] = None):
@@ -116,6 +122,19 @@ class MoRPrediction:
         self.tiles = tiles
         self.kept = tiles if kept is None else kept
         self.kernel_counts = None
+        self.block_counts = None
+
+    def note_blocks(self, n_blocks: jax.Array) -> None:
+        self.block_counts = (n_blocks if self.block_counts is None
+                             else self.block_counts + n_blocks)
+
+    def frac_blocks_issued(self) -> jax.Array:
+        """Share of the kernels' weight blocks DMA'd and multiplied (1.0
+        outside kernel mode: the dense matmul touches every block)."""
+        if self.block_counts is None:
+            return jnp.ones((), jnp.float32)
+        return (self.block_counts[0].astype(jnp.float32)
+                / self.block_counts[1].astype(jnp.float32))
 
     def keep_mask(self, T: int, N: int, tile_m: int, tile_n: int):
         return expand_tile_mask(self.kept, tile_m, tile_n, T, N)
@@ -143,6 +162,7 @@ class MoRPrediction:
         return {"frac_computed": frac_computed,
                 "frac_tiles_live": tiles_live,
                 "frac_tiles_computed": tiles_computed,
+                "frac_blocks_issued": self.frac_blocks_issued(),
                 "frac_mispredicted_zero": jnp.zeros((), jnp.float32),
                 # exact integer tile counters for the obs device block
                 "n_tiles": n_tiles_i,
@@ -370,12 +390,13 @@ class MoRExecutionPlan:
             # zero internally (same capacity-clipped mask as pred.kept);
             # re-applying the keep mask here would be a redundant (T, N)
             # expansion + select on the serving hot path
-            pre, n_live, n_comp = kops.gather_matmul(
+            pre, n_live, n_comp, n_blocks = kops.gather_matmul(
                 x, w, pred.tiles, capacity_frac=self.capacity_frac,
                 capacity_frac_live=self._active_cap, tile_m=self.tile_m,
                 tile_n=self.tile_n, with_counts=True)
-            # the kernel's own tile counters feed the serving telemetry
+            # the kernel's own counters feed the serving telemetry
             pred.kernel_counts = (n_live, n_comp)
+            pred.note_blocks(n_blocks)
             return pre.astype(jnp.float32)
         pre = (x @ w).astype(jnp.float32)
         keep = pred.keep_mask(T, N, self.tile_m, self.tile_n)
@@ -392,9 +413,12 @@ class MoRExecutionPlan:
         if pred is None or self.mode != "kernel":
             return h @ w_down
         from repro.kernels import ops as kops
-        return kops.masked_matmul_kdim(h, w_down, pred.kept,
-                                       tile_m=self.tile_m,
-                                       tile_k=self.tile_n).astype(h.dtype)
+        y, n_blocks = kops.masked_matmul_kdim(h, w_down, pred.kept,
+                                              tile_m=self.tile_m,
+                                              tile_k=self.tile_n,
+                                              with_counts=True)
+        pred.note_blocks(n_blocks)
+        return y.astype(h.dtype)
 
     # -- the mor_relu_matmul / mor_ffn_apply entry points -------------------
     def relu_matmul(self, x: jax.Array, w: jax.Array, *,
@@ -532,7 +556,11 @@ class MoRExecutionPlan:
             h, pred, stats = self._relu_matmul_pred(x, w_up,
                                                     activation=activation,
                                                     row_mask=row_mask)
-        return self.down_matmul(h, w_down, pred), stats
+        y = self.down_matmul(h, w_down, pred)
+        if pred is not None and pred.block_counts is not None:
+            # stats were taken after the first matmul: count every kernel
+            stats["frac_blocks_issued"] = pred.frac_blocks_issued()
+        return y, stats
 
     # -- batched-expert form (MoE): leading E axis on everything -----------
     def expert_ffn(self, eb: jax.Array, w_up: jax.Array, w_down: jax.Array,

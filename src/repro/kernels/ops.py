@@ -79,19 +79,18 @@ def masked_matmul(x: jax.Array, w: jax.Array, tile_mask: jax.Array, *,
 def gather_matmul(x: jax.Array, w: jax.Array, tile_mask: jax.Array, *,
                   capacity: Optional[int] = None, capacity_frac: float = 1.0,
                   capacity_frac_live=None, tile_m: int = 8, tile_n: int = 128,
-                  bk: int = 512, with_counts: bool = False):
-    """``capacity``/``capacity_frac`` provision the STATIC slot list;
+                  with_counts: bool = False):
+    """``capacity``/``capacity_frac`` provision the STATIC tile budget;
     ``capacity_frac_live`` (traced scalar fraction, e.g. the serving
     telemetry's per-layer calibrated budget) clamps the realised live
-    count under it without recompiling.  ``with_counts`` also returns
-    (n_live_total, n_computed) tile counters."""
-    M, K = x.shape
+    count under it without recompiling.  The kernel's blocks follow from
+    the shapes (``gather_matmul.blocks``).  ``with_counts`` also returns
+    (n_live_total, n_computed) tile counters and the kernel's int32
+    [weight blocks issued, weight blocks] pair."""
+    M = x.shape[0]
     N = w.shape[1]
-    bk_ = min(bk, K)
-    if K % bk_ != 0:
-        bk_ = K
-    xp = _pad_to(x, tile_m, bk_)
-    wp = _pad_to(w, bk_, tile_n)
+    xp = _pad_to(x, tile_m, 1)
+    wp = _pad_to(w, 1, tile_n)
     nm = xp.shape[0] // tile_m
     nn = wp.shape[1] // tile_n
     mask = tile_mask
@@ -107,26 +106,28 @@ def gather_matmul(x: jax.Array, w: jax.Array, tile_mask: jax.Array, *,
             jnp.asarray(capacity_frac_live, jnp.float32) * nm * nn)
         ).astype(jnp.int32)
     out = _gm.gather_matmul(xp, wp, mask, capacity=capacity, tile_m=tile_m,
-                            tile_n=tile_n, bk=bk_, cap_live=cap_live,
+                            tile_n=tile_n, cap_live=cap_live,
                             interpret=_interpret(),
                             return_counts=with_counts)
     if with_counts:
-        out, n_live, n_comp = out
-        return out[:M, :N], n_live, n_comp
+        out, n_live, n_comp, n_blocks = out
+        return out[:M, :N], n_live, n_comp, n_blocks
     return out[:M, :N]
 
 
 def masked_matmul_kdim(x: jax.Array, w: jax.Array, tile_mask: jax.Array, *,
                        tile_m: int = 8, tile_k: int = 128,
-                       bn: int = 128) -> jax.Array:
+                       with_counts: bool = False):
     """Contraction-masked matmul (MoR down projection): tile_mask[i, k]
     gates the (tile_m x tile_k) block of x rows feeding output row-block
-    i — dead FFN hidden tiles (exact zeros) are skipped, never MAC'd."""
-    M, K = x.shape
+    i — dead FFN hidden tiles (exact zeros) are skipped, never MAC'd.
+    The kernel's blocks follow from the shapes
+    (``masked_matmul.kdim_blocks``).  ``with_counts`` also returns the
+    kernel's int32 [weight blocks issued, weight blocks] pair."""
+    M = x.shape[0]
     N = w.shape[1]
-    bn_ = min(bn, N)
     xp = _pad_to(x, tile_m, tile_k)
-    wp = _pad_to(w, tile_k, bn_)
+    wp = _pad_to(w, tile_k, 1)
     nm = xp.shape[0] // tile_m
     nk = xp.shape[1] // tile_k
     mask = tile_mask
@@ -135,7 +136,11 @@ def masked_matmul_kdim(x: jax.Array, w: jax.Array, tile_mask: jax.Array, *,
         mask = jnp.pad(mask.astype(jnp.int32),
                        ((0, nm - mask.shape[0]), (0, nk - mask.shape[1])))
     out = _mm.masked_matmul_kdim(xp, wp, mask, tile_m=tile_m, tile_k=tile_k,
-                                 bn=bn_, interpret=_interpret())
+                                 interpret=_interpret(),
+                                 return_counts=with_counts)
+    if with_counts:
+        out, n_blocks = out
+        return out[:M, :N], n_blocks
     return out[:M, :N]
 
 

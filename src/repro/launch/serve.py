@@ -115,7 +115,7 @@ def _mean_layer_stats(aux_list):
         if not rows:
             continue
         for name in ("frac_computed", "frac_tiles_live",
-                     "frac_tiles_computed"):
+                     "frac_tiles_computed", "frac_blocks_issued"):
             vals = [np.asarray(r[name], np.float64) for r in rows
                     if name in r]
             if vals:
@@ -201,7 +201,8 @@ def _run_engine(cfg, params, reqs, *, mor, mor_mode, n_slots, max_len,
             if key in tel:
                 for name, vals in tel[key].items():
                     if name in ("frac_computed", "frac_tiles_live",
-                                "frac_tiles_computed"):
+                                "frac_tiles_computed",
+                                "frac_blocks_issued"):
                         rep[_STAT_PREFIX[key] + name] = \
                             np.round(np.asarray(vals), 4).tolist()
     return eng, results, rep

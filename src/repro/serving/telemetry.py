@@ -78,6 +78,7 @@ class ServingTelemetry:
                     "frac_computed": np.zeros(L),
                     "frac_tiles_live": np.zeros(L),
                     "frac_tiles_computed": np.zeros(L),
+                    "frac_blocks_issued": np.zeros(L),
                     "frac_mispredicted_zero": np.zeros(L),
                 }
             bins = np.clip((live * self.n_bins).astype(np.int64), 0,

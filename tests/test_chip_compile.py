@@ -89,6 +89,29 @@ def test_masked_matmul_kdim_compiles(one_chip):
          ((ROWS // tm, K // 128), jnp.int32)], one_chip)
 
 
+# the benchmark cell's dispatches: 20 decode rows (padded to 24) and a
+# 640-row mixed prefill, with 8 x 128 MoR masks
+@pytest.mark.parametrize("rows", [24, 640])
+def test_gather_matmul_compiles_at_cell_shapes(one_chip, rows):
+    K, N = GRANITE.d_model, GRANITE.d_ff        # gate and up
+    tm, tn = GRANITE.mor.tile_m, GRANITE.mor.tile_n
+    n_tiles = (rows // tm) * (N // tn)
+    _compile(lambda x, w, m: gm.gather_matmul(
+        x, w, m, capacity=n_tiles, tile_m=tm, tile_n=tn, interpret=False),
+        [((rows, K), jnp.bfloat16), ((K, N), jnp.bfloat16),
+         ((rows // tm, N // tn), jnp.int32)], one_chip)
+
+
+@pytest.mark.parametrize("rows", [24, 640])
+def test_masked_matmul_kdim_compiles_at_cell_shapes(one_chip, rows):
+    K, N = GRANITE.d_ff, GRANITE.d_model        # down
+    tm, tk = GRANITE.mor.tile_m, GRANITE.mor.tile_n
+    _compile(lambda x, w, m: mm.masked_matmul_kdim(
+        x, w, m, tile_m=tm, tile_k=tk, interpret=False),
+        [((rows, K), jnp.bfloat16), ((K, N), jnp.bfloat16),
+         ((rows // tm, K // tk), jnp.int32)], one_chip)
+
+
 def _paged_geometry(cfg):
     page = cfg.serve_page
     n_blocks = -(-MAX_LEN // page)

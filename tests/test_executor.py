@@ -184,3 +184,72 @@ def test_as_plan_passthrough_and_wrapping(calibrated):
     p3 = as_plan({"experts": None}, mode="tiled")
     assert not p3.active
     assert not as_plan(None, mode="kernel").active
+
+
+def _column_layer(N, dead):
+    """A MoRLayer whose predictor marks the columns in ``dead`` zero for
+    every input (line -1, no proxy) and keeps every other column."""
+    from repro.core.predictor import make_identity_layer
+    mor = dict(make_identity_layer(N))
+    enable = jnp.zeros((N,), bool).at[dead].set(True)
+    mor.update(enable=enable, is_proxy=jnp.zeros((N,), bool),
+               proxy_slot=jnp.full((N,), -1, jnp.int32),
+               m=jnp.zeros((N,), jnp.float32),
+               b=jnp.full((N,), -1.0, jnp.float32))
+    return mor
+
+
+@pytest.mark.parametrize("dead", ["none", "all"])
+def test_frac_blocks_issued_glu_ffn(dead):
+    """Kernel mode reports the share of weight blocks its three FFN
+    kernels issued: 1.0 when every tile is live, below 1 when whole
+    blocks are dead; the output matches the tiled oracle either way and
+    non-kernel modes report 1.0."""
+    K, N, T = 64, 256, 24
+    mor = _column_layer(N, slice(0, N) if dead == "all" else slice(0, 0))
+    x = jnp.asarray(RNG.normal(size=(T, K)), jnp.float32)
+    wg, wu = (jnp.asarray(RNG.normal(size=(K, N)), jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(RNG.normal(size=(N, K)), jnp.float32)
+    out = {}
+    for mode in ("tiled", "kernel"):
+        plan = as_plan(mor, mode=mode, tile_m=8, tile_n=128)
+        out[mode] = plan.ffn(x, wu, wd, activation="relu", w_gate=wg)
+    (y_t, st_t), (y_k, st_k) = out["tiled"], out["kernel"]
+    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_t),
+                               rtol=2e-4, atol=2e-3)
+    assert float(st_t["frac_blocks_issued"]) == 1.0
+    assert float(st_k["frac_tiles_computed"]) == \
+        float(st_t["frac_tiles_computed"])
+    if dead == "all":
+        assert float(st_k["frac_blocks_issued"]) < 1.0
+        assert not np.any(np.asarray(y_k))
+    else:
+        assert float(st_k["frac_blocks_issued"]) == 1.0
+
+
+def test_frac_blocks_issued_expert_ffn():
+    """Under vmap (MoE expert_ffn): an expert with no routed rows issues
+    no block, the others issue every block; outputs match tiled mode."""
+    E, C, K, N = 3, 16, 64, 256
+    one = _column_layer(N, slice(0, 0))
+    em = jax.tree_util.tree_map(
+        lambda a: jnp.broadcast_to(a[None], (E,) + a.shape), one)
+    eb = jnp.asarray(RNG.normal(size=(E, C, K)), jnp.float32)
+    wg, wu = (jnp.asarray(RNG.normal(size=(E, K, N)), jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(RNG.normal(size=(E, N, K)), jnp.float32)
+    row_mask = jnp.ones((E, C), bool).at[0].set(False)
+    eb = jnp.where(row_mask[..., None], eb, 0.0)
+    res = {}
+    for mode in ("tiled", "kernel"):
+        plan = MoRExecutionPlan(em, mode=mode, tile_m=8, tile_n=128)
+        res[mode] = plan.expert_ffn(eb, wu, wd, activation="relu",
+                                    w_gate=wg, row_mask=row_mask)
+    np.testing.assert_allclose(np.asarray(res["kernel"][0]),
+                               np.asarray(res["tiled"][0]),
+                               rtol=2e-4, atol=2e-3)
+    frac = np.asarray(res["kernel"][1]["frac_blocks_issued"])
+    assert frac.shape == (E,)
+    assert frac[0] < 1.0 and np.all(frac[1:] == 1.0)
+    assert np.all(np.asarray(res["tiled"][1]["frac_blocks_issued"]) == 1.0)

@@ -265,7 +265,7 @@ def test_gather_matmul_cap_counts_and_zeroes():
         mask = jnp.asarray(rng.random((nm, nn)) > 0.4)
         cap_frac = float(rng.uniform(0.2, 1.0))
         cap_live = float(rng.uniform(0.1, 1.0))
-        out, n_live, n_comp = kops.gather_matmul(
+        out, n_live, n_comp, _ = kops.gather_matmul(
             x, w, mask, capacity_frac=cap_frac,
             capacity_frac_live=cap_live, tile_m=tm, tile_n=tn,
             with_counts=True)

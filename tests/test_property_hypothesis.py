@@ -119,7 +119,7 @@ def test_gather_matmul_cap_live_clamp(nm, nn, cap_frac, cap_live, seed):
     x = jnp.asarray(rng.normal(size=(nm * tm, 32)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(32, nn * tn)), jnp.float32)
     mask = jnp.asarray(rng.random((nm, nn)) > 0.4)
-    out, n_live, n_comp = kops.gather_matmul(
+    out, n_live, n_comp, _ = kops.gather_matmul(
         x, w, mask, capacity_frac=cap_frac, capacity_frac_live=cap_live,
         tile_m=tm, tile_n=tn, with_counts=True)
     n_tiles = nm * nn
